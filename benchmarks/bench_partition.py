@@ -103,6 +103,9 @@ def run(quick: bool = True):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     env.pop("XLA_FLAGS", None)
+    # the child counts on forced host devices: keep it off any chip the
+    # parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-c", MEASURE_SCRIPT, str(int(quick))],
         capture_output=True,
@@ -119,6 +122,7 @@ def run(quick: bool = True):
     skew_1d = _mean(rows, "1d", "serve_matrix_skew")
     skew_hub = _mean(rows, "hub", "serve_matrix_skew")
     return {
+        "platform": "cpu",
         "rows": rows,
         # CI-gated booleans (deterministic — counters, not wall clocks)
         "bit_exact_all": bool(res["bit_exact_all"]),

@@ -164,6 +164,9 @@ def run(quick: bool = True):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     env.pop("XLA_FLAGS", None)
+    # the child counts on forced host devices: keep it off any chip the
+    # parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-c", MEASURE_SCRIPT, str(int(quick))],
         capture_output=True,
@@ -186,6 +189,7 @@ def run(quick: bool = True):
     serving_speedup = _speedups(res["serving"])
     streaming_speedup = _speedups(res["streaming"])
     return {
+        "platform": "cpu",
         "serving": res["serving"],
         "streaming": res["streaming"],
         "model_agreement_all": bool(agree and all(agree)),

@@ -177,6 +177,9 @@ def measured():
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     env.pop("XLA_FLAGS", None)
+    # the child counts on forced host devices: keep it off any chip the
+    # parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", MEASURE_SCRIPT],
                        capture_output=True, text=True, env=env, timeout=900)
     if r.returncode != 0:
@@ -189,6 +192,7 @@ def run(quick: bool = True):
         "modeled": modeled(quick),
         "hub_partition": hub_partition_rows(quick),
         "measured_8hostdev": measured(),
+        "measured_platform": "cpu",
         "paper_ref": "Figs. 9/10",
     }
 

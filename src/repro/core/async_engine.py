@@ -20,14 +20,18 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-
-from .compat import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .intersect import count_bsearch_jnp, count_pairwise_jnp, tpu_regime_rule
 from .rma import ShardedLCCProblem
 
-__all__ = ["lcc_pipelined", "make_lcc_fn", "run_distributed_lcc"]
+__all__ = [
+    "device_args",
+    "lcc_mesh",
+    "lcc_pipelined",
+    "make_lcc_fn",
+    "run_distributed_lcc",
+]
 
 
 def _shard_body(
@@ -127,7 +131,7 @@ def make_lcc_fn(
     )
     sharded = P(axis)
     repl = P()
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(sharded, sharded, sharded, sharded, sharded, sharded, repl),
@@ -135,6 +139,28 @@ def make_lcc_fn(
         check_vma=False,
     )
     return jax.jit(fn)
+
+
+def lcc_mesh(p: int) -> Mesh:
+    """The engine's 1-D ``("dev",)`` mesh over the first ``p`` devices."""
+    devs = jax.devices()
+    if len(devs) < p:
+        raise RuntimeError(
+            f"need {p} devices, have {len(devs)} {devs[0].platform} devices"
+        )
+    return Mesh(np.array(devs[:p]), ("dev",))
+
+
+def device_args(prob: ShardedLCCProblem, mesh: Mesh, *, axis: str = "dev"):
+    """The engine's inputs, each placed straight onto its shards (rank k's
+    slice goes to device k; the cache rows are replicated), so no single
+    device stages the whole problem."""
+    sharded = NamedSharding(mesh, P(axis))
+    return tuple(
+        jax.device_put(x, sharded)
+        for x in (prob.rows_ext, prob.degrees, prob.edge_u, prob.edge_vc,
+                  prob.edge_mask, prob.serve_idx)
+    ) + (jax.device_put(prob.cache_rows, NamedSharding(mesh, P())),)
 
 
 def lcc_pipelined(
@@ -145,21 +171,9 @@ def lcc_pipelined(
 ):
     """Run the engine; returns (t_per_vertex [p, n_loc], lcc [p, n_loc])."""
     if mesh is None:
-        devs = np.array(jax.devices()[: prob.p])
-        assert devs.size == prob.p, (
-            f"need {prob.p} devices, have {len(jax.devices())}"
-        )
-        mesh = Mesh(devs, ("dev",))
+        mesh = lcc_mesh(prob.p)
     fn = make_lcc_fn(prob, mesh, method=method)
-    t, lcc = fn(
-        jnp.asarray(prob.rows_ext),
-        jnp.asarray(prob.degrees),
-        jnp.asarray(prob.edge_u),
-        jnp.asarray(prob.edge_vc),
-        jnp.asarray(prob.edge_mask),
-        jnp.asarray(prob.serve_idx),
-        jnp.asarray(prob.cache_rows),
-    )
+    t, lcc = fn(*device_args(prob, mesh))
     return np.asarray(t), np.asarray(lcc)
 
 

@@ -79,10 +79,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core.compat import shard_map
 from ..core.intersect import count_bsearch_jnp
 from ..kernels.bucketing import pow2_ceil
 from ..kernels.intersect_count import intersect_count
+from ..kernels.ops import default_interpret
 from ..obs import trace as obs_trace
 
 __all__ = [
@@ -109,28 +109,44 @@ _CAP_WINDOW = 16
 
 
 def ensure_host_devices(n: int, *, strict: bool = True) -> int:
-    """Make at least ``n`` JAX devices available, forcing host-platform
-    devices when none exist yet.
+    """Make at least ``n`` JAX devices available: host devices on the
+    CPU, real chips on an accelerator.
 
-    Appends ``--xla_force_host_platform_device_count=n`` to ``XLA_FLAGS``
-    — *preserving* any flags already set by the user or CI, and never
-    overriding an existing device-count directive (jax pins the device
-    count at first backend init, so an explicit external value must
-    win). An existing directive's *value* is parsed and compared
-    against ``n``: a smaller pinned count fails here, immediately and
-    by name, instead of surfacing later as a confusing generic device
-    shortage. Returns the device count actually available; with
-    ``strict`` raises if it is still smaller than ``n`` (e.g. jax was
-    already initialized single-device before this call). This is the
-    one home of the flag-preserving logic — drivers, benchmarks, and
-    subprocess test scripts call it instead of hand-editing
-    ``XLA_FLAGS``."""
+    On the CPU, appends ``--xla_force_host_platform_device_count=n`` to
+    ``XLA_FLAGS`` — *preserving* any flags already set by the user or
+    CI, and never overriding an existing device-count directive (jax
+    pins the device count at first backend init, so an explicit
+    external value must win). An existing directive's *value* is parsed
+    and compared against ``n``: a smaller pinned count fails here,
+    immediately and by name, instead of surfacing later as a confusing
+    generic device shortage. The flag shapes only the CPU backend, so
+    when JAX comes up on an accelerator the environment is restored and
+    the chips themselves are counted. Returns the device count actually
+    available; with ``strict`` raises if it is still smaller than ``n``
+    (e.g. jax was already initialized single-device before this call,
+    or the host has fewer chips). This is the one home of the
+    flag-preserving logic — drivers, benchmarks, and subprocess test
+    scripts call it instead of hand-editing ``XLA_FLAGS``."""
     n = int(n)
+    had_flags = "XLA_FLAGS" in os.environ
     flags = os.environ.get("XLA_FLAGS", "")
     m = re.search(re.escape(_DEVCOUNT_FLAG) + r"\s*=\s*(\d+)", flags)
     if m is None:
         os.environ["XLA_FLAGS"] = f"{flags} {_DEVCOUNT_FLAG}={n}".strip()
-    have = len(jax.devices())  # first call initializes with the flags
+    devs = jax.devices()  # first call initializes with the flags
+    have = len(devs)
+    platform = devs[0].platform
+    if platform != "cpu":
+        if had_flags:
+            os.environ["XLA_FLAGS"] = flags
+        else:
+            os.environ.pop("XLA_FLAGS", None)
+        if strict and have < n:
+            raise RuntimeError(
+                f"need {n} devices for SPMD execution but this {platform} "
+                f"host has {have} ({devs[0].device_kind})"
+            )
+        return have
     if strict and have < n:
         if m is not None and int(m.group(1)) < n:
             raise RuntimeError(
@@ -309,9 +325,10 @@ class _ResidentShardBuffer:
         self._upload_full()
 
     def _upload_full(self) -> None:
+        # straight from the host mirror onto each rank's device (no
+        # staging of the whole buffer on device 0)
         self.device = jax.device_put(
-            jnp.asarray(self.mirror),
-            NamedSharding(self.mesh, P(self.axis)),
+            self.mirror, NamedSharding(self.mesh, P(self.axis))
         )
 
     def _alloc(self, k: int, protected: set) -> int:
@@ -627,10 +644,11 @@ class SpmdIntersectExecutor:
         self.p = int(p if p is not None else part.p)
         self.axis = axis
         if use_kernel is None:
-            use_kernel = jax.default_backend() == "tpu"
+            # compiled kernel on the chip, jnp binary search on the CPU
+            use_kernel = not default_interpret()
         self.use_kernel = bool(use_kernel)
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = default_interpret()
         self.interpret = bool(interpret)
         self.block_e = int(block_e)
         if mesh is None:
@@ -638,9 +656,9 @@ class SpmdIntersectExecutor:
             if len(devs) < self.p:
                 raise RuntimeError(
                     f"SPMD execution at p={self.p} needs {self.p} devices "
-                    f"but only {len(devs)} exist — call "
-                    f"ensure_host_devices({self.p}) (or set XLA_FLAGS="
-                    f"{_DEVCOUNT_FLAG}={self.p}) before the first jax use"
+                    f"but only {len(devs)} {devs[0].platform} devices "
+                    f"exist — call ensure_host_devices({self.p}) before "
+                    "the first jax use"
                 )
             mesh = Mesh(np.array(devs[: self.p]), (axis,))
         self.mesh = mesh
@@ -705,7 +723,7 @@ class SpmdIntersectExecutor:
             )
             sh = P(self.axis)
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     body,
                     mesh=self.mesh,
                     in_specs=(sh, sh),
@@ -731,7 +749,7 @@ class SpmdIntersectExecutor:
             )
             sh = P(self.axis)
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     body,
                     mesh=self.mesh,
                     in_specs=(sh, sh, sh, sh, sh),
@@ -1082,6 +1100,12 @@ class SpmdIntersectExecutor:
         )
         fn_p = self._fn_pairs(h, f_pad, w, tuple(pair_cfg))
         _pack.__exit__(None, None, None)
+        if self.use_kernel:
+            obs_trace.instant(
+                "pallas_kernel", cat="kernel", kernel="spmd_pairs",
+                interpret=self.interpret, pairs=int(a_idx.size),
+                buckets=len(pair_cfg),
+            )
 
         unit.n_collectives += 1 if has_serve else 0
         unit.n_pairs += n_pairs

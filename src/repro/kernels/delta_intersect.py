@@ -5,10 +5,12 @@ pipeline — |adj(u) ∩ adj(v)| over padded sorted rows — but a streaming
 batch has a data-dependent number of row pairs, while ``intersect_count``
 requires the edge dimension to be a multiple of ``block_e``. This wrapper:
 
-- pads the pair batch up to the next ``block_e`` multiple with all-sentinel
-  phantom rows (they intersect nothing, so the padding counts are 0), and
+- pads the pair batch up to the next power of two (at least 8) with
+  all-sentinel phantom rows (they intersect nothing, so the padding
+  counts are 0), so the compiled grid shapes stay logarithmic in the
+  batch size, and
 - clamps ``block_e`` down for tiny batches so a 3-edge delta doesn't pay
-  a 128-row program.
+  a 128-row program (one block then spans the whole padded batch).
 
 ``delta_intersect_masks`` is the companion membership primitive: the
 incremental LCC update needs the *identities* of the closing vertices
@@ -19,12 +21,13 @@ the streaming tests cross-check the two paths.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import trace as obs_trace
 from .bucketing import pow2_ceil
 from .intersect_count import intersect_count as _intersect
+from .ops import default_interpret
 
 __all__ = ["delta_intersect_counts", "delta_intersect_masks"]
 
@@ -54,9 +57,14 @@ def delta_intersect_counts(
     if e == 0:
         return np.zeros((0,), np.int64)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     be = min(block_e, pow2_ceil(e, 8))
-    e_pad = -(-e // be) * be
+    e_pad = -(-pow2_ceil(e, 8) // be) * be
+    obs_trace.instant(
+        "pallas_kernel", cat="kernel", kernel="intersect_count",
+        interpret=bool(interpret), pairs=e_pad, wa=rows_a.shape[1],
+        wb=rows_b.shape[1],
+    )
     cnt = _intersect(
         jnp.asarray(_pad_pairs(rows_a, e_pad, sentinel)),
         jnp.asarray(_pad_pairs(rows_b, e_pad, sentinel)),

@@ -1,8 +1,10 @@
 """jit'd public wrappers over the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels TARGET TPU and are validated against ref.py in interpret mode).
-On real TPU backends pass ``interpret=False`` (or rely on the default).
+The kernels target the TPU. ``default_interpret`` is the one place that
+decides how they run: compiled by Mosaic on a TPU, in the Pallas
+interpreter on the CPU (where the tests validate them against
+``ref.py``), and an error on any other platform rather than a silent
+fallback. Every wrapper's ``interpret=None`` resolves through it.
 """
 from __future__ import annotations
 
@@ -26,7 +28,17 @@ __all__ = [
 
 
 def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """True on the CPU (Pallas interpreter), False on a TPU (compiled);
+    raises on any other platform."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels run compiled on a TPU or interpreted on the "
+        f"CPU; JAX's default backend is {platform!r}"
+    )
 
 
 def intersect_count(rows_a, rows_b, *, sentinel, block_e=128, interpret=None):

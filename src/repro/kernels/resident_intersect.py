@@ -1,20 +1,14 @@
-"""Pallas TPU kernel: intersect query rows against device-resident slots.
+"""Intersect query rows against device-resident slots.
 
 The device tier (``repro.device.ResidencyManager``) keeps the
 degree-scored hot adjacency rows persistently resident in a padded
 ``[slots, max_width]`` device buffer. The host-side intersection path
 would gather those rows back to host, re-pack and re-upload them per
 kernel call — exactly the per-epoch refetch cost the paper's CLaMPI
-cache removes one level up. This kernel removes it on-device: the
-resident operand never leaves the device.
-
-The gather is fused into the schedule via **scalar prefetch**
-(``PrefetchScalarGridSpec``): the per-pair slot indices are prefetched
-to SMEM before the kernel body runs, and each input's ``index_map``
-uses them to DMA the *resident row of that pair's slot* straight from
-the residency buffer into VMEM — one program per pair, block
-``[1, W]`` vs ``[1, WB]``, the same all-pairs VPU compare (chunked over
-LANES) as ``intersect_count``. Two layouts:
+cache removes one level up. Here the resident operand never leaves the
+device: each pair's resident row is gathered on device by slot index
+and counted by the same Pallas ``intersect_count`` kernel every other
+path uses. Two layouts:
 
 - ``rows_b`` given   — resident slot vs a packed (uploaded) query row;
 - ``slots_b`` given  — both sides resident: two gathers, zero upload.
@@ -36,76 +30,18 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
+from ..obs import trace as obs_trace
 from .bucketing import pow2_ceil
+from .intersect_count import intersect_count
+from .ops import default_interpret
 
 __all__ = ["resident_intersect", "resident_intersect_counts"]
 
-LANES = 128
 
-
-def _kernel(*refs, sentinel: int, wb: int):
-    # trailing refs are (a_ref [1, W], b_ref [1, WB], out_ref [1]); any
-    # leading refs are the prefetched slot arrays (unused in the body —
-    # they drive the index_maps).
-    a_ref, b_ref, out_ref = refs[-3], refs[-2], refs[-1]
-    a = a_ref[0]  # [W]
-    valid_a = a < sentinel
-    acc = jnp.zeros((), jnp.int32)
-    for lo in range(0, wb, LANES):
-        hi = min(lo + LANES, wb)
-        b = b_ref[0, lo:hi]  # [chunk]
-        eq = a[:, None] == b[None, :]
-        eq = jnp.logical_and(eq, valid_a[:, None])
-        acc = acc + eq.sum().astype(jnp.int32)
-    out_ref[0] = acc
-
-
-@functools.partial(jax.jit, static_argnames=("sentinel", "interpret"))
-def _vs_rows(slots_a, residency, rows_b, *, sentinel: int, interpret: bool):
-    e = slots_a.shape[0]
-    _, w = residency.shape
-    _, wb = rows_b.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(e,),
-        in_specs=[
-            pl.BlockSpec((1, w), lambda i, sa: (sa[i], 0)),
-            pl.BlockSpec((1, wb), lambda i, sa: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1,), lambda i, sa: (i,)),
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, sentinel=sentinel, wb=wb),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((e,), jnp.int32),
-        interpret=interpret,
-    )(slots_a, residency, rows_b)
-
-
-@functools.partial(jax.jit, static_argnames=("sentinel", "interpret"))
-def _vs_slots(slots_a, slots_b, residency, *, sentinel: int, interpret: bool):
-    e = slots_a.shape[0]
-    _, w = residency.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(e,),
-        in_specs=[
-            pl.BlockSpec((1, w), lambda i, sa, sb: (sa[i], 0)),
-            pl.BlockSpec((1, w), lambda i, sa, sb: (sb[i], 0)),
-        ],
-        out_specs=pl.BlockSpec((1,), lambda i, sa, sb: (i,)),
-    )
-    return pl.pallas_call(
-        functools.partial(_kernel, sentinel=sentinel, wb=w),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((e,), jnp.int32),
-        interpret=interpret,
-    )(slots_a, slots_b, residency, residency)
-
-
+@functools.partial(
+    jax.jit, static_argnames=("sentinel", "block_e", "interpret")
+)
 def resident_intersect(
     residency: jnp.ndarray,  # [S, W] int32 resident rows, sentinel-padded
     slots_a: jnp.ndarray,  # [E] int32 slot per pair
@@ -113,22 +49,20 @@ def resident_intersect(
     *,
     slots_b: Optional[jnp.ndarray] = None,  # [E] both-resident variant
     sentinel: int,
+    block_e: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """``|residency[slots_a[e]] ∩ B[e]|`` per pair (int32 [E]).
 
     ``B`` is ``rows_b[e]`` (one uploaded side) or
-    ``residency[slots_b[e]]`` (fully resident). E must match the padded
-    grid exactly — use ``resident_intersect_counts`` for ragged batches.
+    ``residency[slots_b[e]]`` (fully resident). E must be a multiple of
+    ``block_e`` — use ``resident_intersect_counts`` for ragged batches.
     """
     assert (rows_b is None) != (slots_b is None), "pass rows_b XOR slots_b"
-    if slots_b is not None:
-        return _vs_slots(
-            slots_a, slots_b, residency, sentinel=sentinel,
-            interpret=interpret,
-        )
-    return _vs_rows(
-        slots_a, residency, rows_b, sentinel=sentinel, interpret=interpret
+    a = jnp.take(residency, slots_a, axis=0)
+    b = rows_b if slots_b is None else jnp.take(residency, slots_b, axis=0)
+    return intersect_count(
+        a, b, sentinel=sentinel, block_e=block_e, interpret=interpret
     )
 
 
@@ -153,7 +87,7 @@ def resident_intersect_counts(
     if e == 0:
         return np.zeros((0,), np.int64)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     res = (
         residency
         if isinstance(residency, jnp.ndarray)
@@ -162,22 +96,23 @@ def resident_intersect_counts(
     e_pad = pow2_ceil(e, 8)
     sa = np.zeros(e_pad, np.int32)
     sa[:e] = slots_a
+    kw = dict(sentinel=sentinel, block_e=min(128, e_pad), interpret=interpret)
+    obs_trace.instant(
+        "pallas_kernel", cat="kernel", kernel="resident_intersect",
+        layout="rows" if slots_b is None else "slots",
+        interpret=bool(interpret), pairs=e_pad, w=int(res.shape[1]),
+    )
     if slots_b is not None:
         slots_b = np.ascontiguousarray(slots_b, np.int32)
         assert slots_b.shape[0] == e
         sb = np.zeros(e_pad, np.int32)
         sb[:e] = slots_b
-        cnt = _vs_slots(
-            jnp.asarray(sa), jnp.asarray(sb), res,
-            sentinel=sentinel, interpret=interpret,
-        )
+        cnt = resident_intersect(res, jnp.asarray(sa), slots_b=jnp.asarray(sb),
+                                 **kw)
     else:
         rows_b = np.ascontiguousarray(rows_b, np.int32)
         assert rows_b.shape[0] == e
         rb = np.full((e_pad, rows_b.shape[1]), sentinel, np.int32)
         rb[:e] = rows_b
-        cnt = _vs_rows(
-            jnp.asarray(sa), res, jnp.asarray(rb),
-            sentinel=sentinel, interpret=interpret,
-        )
+        cnt = resident_intersect(res, jnp.asarray(sa), jnp.asarray(rb), **kw)
     return np.asarray(cnt[:e], np.int64)

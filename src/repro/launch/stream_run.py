@@ -144,6 +144,9 @@ def main(argv=None):
     if args.rebalance and args.partition != "hub":
         ap.error("--rebalance migrates hub-partition cuts; pass "
                  "--partition hub")
+    from .chip import device_summary, enable_compile_cache
+
+    enable_compile_cache()
     tracer = None
     if args.trace:
         from ..obs import trace as obs_trace
@@ -161,6 +164,7 @@ def main(argv=None):
         from ..distributed.spmd_runtime import ensure_host_devices
 
         ensure_host_devices(ranks)
+    dev = device_summary()
 
     from ..core.rma import assert_problems_equal, build_sharded_problem
     from ..graphs.rmat import rmat_adversarial_stream, rmat_stream
@@ -174,6 +178,8 @@ def main(argv=None):
           f"{', hub-targeted' if args.adversarial else ''}) in "
           f"{args.batches} batches of {batch_size}, ranks={ranks}"
           + ("  [SPMD device mesh]" if args.spmd else ""))
+    print(f"devices: {dev['count']} {dev['platform']} ({dev['kind']}); "
+          f"Pallas kernels {'off' if args.no_kernel else 'on'}")
 
     partition = None
     if args.partition == "hub":

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..core.compat import shard_map
 from .common import shard, silu, trunc_normal
 
 __all__ = ["moe_init", "moe_param_specs", "moe_apply", "moe_apply_local_ep"]
@@ -186,7 +185,7 @@ def moe_apply_local_ep(
         # the ONLY collective: combine partial expert outputs across columns
         return jax.lax.psum(y, model_axis_name)
 
-    out = shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(dp, None), P(dp, None, None), P(dp, None, None),

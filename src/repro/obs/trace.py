@@ -38,6 +38,8 @@ docs/observability.md for the full map):
     spmd_pack         host-side packing of one SPMD execution unit
     spmd_patch        resident-buffer drift patched to device (H2D)
     spmd_overlap_wait the reconciliation barrier of a pipelined unit
+    pallas_kernel     one Pallas kernel dispatch (instant): which
+                      kernel, whether it ran interpreted, its shape
 
 Fine mode (``enable_tracing(fine=True)``) additionally emits per-entry
 ``cache_admit``/``cache_evict`` instants from inside the cache — useful
@@ -75,6 +77,7 @@ PHASES = (
     "spmd_pack",
     "spmd_patch",
     "spmd_overlap_wait",
+    "pallas_kernel",
 )
 
 

@@ -37,6 +37,7 @@ from ..core.triangles import lcc_scores, triangles_per_vertex
 from ..obs import trace as obs_trace
 from ..kernels.bucketing import pack_rows, width_classes
 from ..kernels.delta_intersect import delta_intersect_masks
+from ..kernels.ops import default_interpret
 from ..kernels.point_query import batched_pair_counts
 from ..kernels.resident_intersect import resident_intersect_counts
 from .provider import DirectRowProvider, RuntimeRowProvider
@@ -84,9 +85,8 @@ class QueryEngine:
         self.store = store  # DynamicCSR or CSRGraph (row/degrees/n)
         self.provider = provider or DirectRowProvider(store)
         if use_kernel is None:
-            import jax
-
-            use_kernel = jax.default_backend() == "tpu"
+            # compiled kernel on the chip, host binary search on the CPU
+            use_kernel = not default_interpret()
         self.use_kernel = use_kernel
         self.block_e = block_e
         self.interpret = interpret

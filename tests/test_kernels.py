@@ -21,6 +21,8 @@ def pad_sorted(rng, e, w, sentinel, max_fill=None):
     (128, 16, 32, 64),
     (256, 64, 128, 128),
     (128, 8, 200, 128),  # non-multiple-of-128 width
+    (256, 130, 70, 128),  # B padded to whole chunks with the sentinel
+    (8, 3, 37, 8),  # tiny streaming batch: one block spans it
 ])
 def test_intersect_count(e, wa, wb, block_e):
     rng = np.random.default_rng(0)
@@ -31,6 +33,19 @@ def test_intersect_count(e, wa, wb, block_e):
                               interpret=True)
     want = ref.intersect_count_ref(a, b, sentinel=sent)
     assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("backend,want", [
+    ("cpu", True), ("tpu", False), ("gpu", None),
+])
+def test_default_interpret_by_platform(monkeypatch, backend, want):
+    """Interpreted on the CPU, compiled on a TPU, refused elsewhere."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if want is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops.default_interpret()
+    else:
+        assert ops.default_interpret() is want
 
 
 @pytest.mark.parametrize("e,w,block_e", [(256, 8, 128), (512, 33, 256)])

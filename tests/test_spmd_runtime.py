@@ -14,6 +14,7 @@ import json
 import os
 import subprocess
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -134,6 +135,24 @@ def test_ensure_host_devices_parses_existing_value(monkeypatch):
     assert os.environ["XLA_FLAGS"] == (
         "--xla_force_host_platform_device_count = 16"
     )
+
+
+def test_ensure_host_devices_counts_chips_on_a_tpu(monkeypatch):
+    """On an accelerator the host-device flag shapes nothing: the
+    environment is left as it was, the chips themselves are counted, and
+    a shortage names the platform."""
+    from repro.distributed import spmd_runtime
+
+    chip = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    monkeypatch.setattr(spmd_runtime.jax, "devices", lambda: [chip])
+    monkeypatch.setenv("XLA_FLAGS", "--xla_foo=7")
+    assert spmd_runtime.ensure_host_devices(1) == 1
+    assert os.environ["XLA_FLAGS"] == "--xla_foo=7"
+    with pytest.raises(RuntimeError, match="this tpu host has 1"):
+        spmd_runtime.ensure_host_devices(4)
+    monkeypatch.delenv("XLA_FLAGS")
+    assert spmd_runtime.ensure_host_devices(4, strict=False) == 1
+    assert "XLA_FLAGS" not in os.environ
 
 
 # --------------------------------------------------------------------------
