@@ -11,6 +11,9 @@ overlap into DMA/compute overlap.
 Compute per edge: gather row_u (local) and row_v (local | cache | fetch
 buffer — one combined gather), count |row_u ∩ row_v| with the regime-split
 intersection, and segment-accumulate into S(u). LCC follows Eq. (2).
+The stages carry the named scopes ``lcc.fetch``, ``lcc.gather``,
+``lcc.count``, ``lcc.accumulate`` and ``lcc.finalize`` in the compiled
+program's op metadata, which a profiler trace shows.
 """
 from __future__ import annotations
 
@@ -22,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs import trace as obs_trace
 from .intersect import count_bsearch_jnp, count_pairwise_jnp, tpu_regime_rule
 from .rma import ShardedLCCProblem
 
@@ -63,11 +67,12 @@ def _shard_body(
 
     def fetch(r):
         # rows this device serves in round r -> one a2a -> rows it needs
-        to_send = rows_ext[serve_idx[r]]  # [p, S_max, W]
-        got = jax.lax.all_to_all(
-            to_send, axis, split_axis=0, concat_axis=0, tiled=False
-        )
-        return got.reshape(p * s_max, w)
+        with jax.named_scope("lcc.fetch"):
+            to_send = rows_ext[serve_idx[r]]  # [p, S_max, W]
+            got = jax.lax.all_to_all(
+                to_send, axis, split_axis=0, concat_axis=0, tiled=False
+            )
+            return got.reshape(p * s_max, w)
 
     def count(rows_a, rows_b, deg_a, deg_b):
         if method == "bsearch":
@@ -89,27 +94,31 @@ def _shard_body(
         # double buffering: issue next round's fetch before this round's
         # compute so the collective overlaps the intersection work.
         fetched_nxt = fetch(jnp.minimum(r + 1, n_rounds - 1))
-        combined = jnp.concatenate([rows_ext, cache_rows, fetched_cur], 0)
-        eu = jax.lax.dynamic_slice(edge_u, (r * e_chunk,), (e_chunk,))
-        evc = jax.lax.dynamic_slice(edge_vc, (r * e_chunk,), (e_chunk,))
-        msk = jax.lax.dynamic_slice(edge_mask, (r * e_chunk,), (e_chunk,))
-        rows_a = rows_ext[eu]
-        rows_b = combined[evc]
-        deg_a = deg_ext[eu]
-        deg_b = (rows_b < sentinel).sum(-1)
-        cnt = count(rows_a, rows_b, deg_a, deg_b)
-        acc = acc.at[eu].add(jnp.where(msk, cnt, 0))
+        with jax.named_scope("lcc.gather"):
+            combined = jnp.concatenate([rows_ext, cache_rows, fetched_cur], 0)
+            eu = jax.lax.dynamic_slice(edge_u, (r * e_chunk,), (e_chunk,))
+            evc = jax.lax.dynamic_slice(edge_vc, (r * e_chunk,), (e_chunk,))
+            msk = jax.lax.dynamic_slice(edge_mask, (r * e_chunk,), (e_chunk,))
+            rows_a = rows_ext[eu]
+            rows_b = combined[evc]
+            deg_a = deg_ext[eu]
+        with jax.named_scope("lcc.count"):
+            deg_b = (rows_b < sentinel).sum(-1)
+            cnt = count(rows_a, rows_b, deg_a, deg_b)
+        with jax.named_scope("lcc.accumulate"):
+            acc = acc.at[eu].add(jnp.where(msk, cnt, 0))
         return fetched_nxt, acc
 
     acc0 = jnp.zeros((n_loc + 1,), jnp.int32)
     fetched0 = fetch(0)
     _, acc = jax.lax.fori_loop(0, n_rounds, body, (fetched0, acc0))
-    s = acc[:n_loc]
-    t = s // 2  # undirected: each neighbor-edge seen twice in S(i)
-    deg = degrees.astype(jnp.float32)
-    denom = deg * (deg - 1.0)
-    lcc = jnp.where(denom > 0, 2.0 * t.astype(jnp.float32) / denom, 0.0)
-    return t[None], lcc[None]
+    with jax.named_scope("lcc.finalize"):
+        s = acc[:n_loc]
+        t = s // 2  # undirected: each neighbor-edge seen twice in S(i)
+        deg = degrees.astype(jnp.float32)
+        denom = deg * (deg - 1.0)
+        lcc = jnp.where(denom > 0, 2.0 * t.astype(jnp.float32) / denom, 0.0)
+        return t[None], lcc[None]
 
 
 def make_lcc_fn(
@@ -154,13 +163,18 @@ def lcc_mesh(p: int) -> Mesh:
 def device_args(prob: ShardedLCCProblem, mesh: Mesh, *, axis: str = "dev"):
     """The engine's inputs, each placed straight onto its shards (rank k's
     slice goes to device k; the cache rows are replicated), so no single
-    device stages the whole problem."""
+    device stages the whole problem. Returns once they are on the
+    devices: the set-up span ``setup.place``, with the arrays' ``bytes``."""
     sharded = NamedSharding(mesh, P(axis))
-    return tuple(
-        jax.device_put(x, sharded)
-        for x in (prob.rows_ext, prob.degrees, prob.edge_u, prob.edge_vc,
-                  prob.edge_mask, prob.serve_idx)
-    ) + (jax.device_put(prob.cache_rows, NamedSharding(mesh, P())),)
+    with obs_trace.setup_span("setup.place") as span:
+        args = tuple(
+            jax.device_put(x, sharded)
+            for x in (prob.rows_ext, prob.degrees, prob.edge_u, prob.edge_vc,
+                      prob.edge_mask, prob.serve_idx)
+        ) + (jax.device_put(prob.cache_rows, NamedSharding(mesh, P())),)
+        jax.block_until_ready(args)
+        span.set(bytes=sum(x.nbytes for x in args))
+    return args
 
 
 def lcc_pipelined(

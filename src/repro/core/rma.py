@@ -25,6 +25,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..obs import trace as obs_trace
 from .cache import CacheStats, ClampiCache, NetworkModel, StaticDegreeCache
 from .csr import CSRGraph, to_padded_rows
 from .partition import Partition1D, partition_1d
@@ -33,6 +34,7 @@ __all__ = [
     "ShardedLCCProblem",
     "ScheduleWidthOverflow",
     "build_sharded_problem",
+    "schedule_counts",
     "assert_problems_equal",
     "RMATraceStats",
     "simulate_rma_lcc",
@@ -327,7 +329,42 @@ def build_sharded_problem(
     partition — 1D by default; pass ``part`` (any owner/lo/hi/sizes
     contract holder, e.g. ``partition_hub``) to compile against
     variable cuts. Per-device row slabs are sized to the LARGEST block
-    so the ``[p, n_loc, ...]`` layout stays rectangular."""
+    so the ``[p, n_loc, ...]`` layout stays rectangular.
+
+    Recorded as the set-up span ``setup.schedule``, with the counts of
+    ``schedule_counts``."""
+    with obs_trace.setup_span("setup.schedule") as span:
+        prob = _build_sharded_problem(csr, p, n_rounds, cache, width,
+                                      dedup_rounds, part)
+        span.set(**schedule_counts(prob, csr.degrees))
+    return prob
+
+
+def schedule_counts(prob: ShardedLCCProblem, deg: np.ndarray) -> Dict[str, int]:
+    """The schedule's shape and how much of its compare work is real.
+
+    The padded all-pairs compare evaluates ``e_max * width**2`` slot
+    pairs per epoch on every device (``padded_compares``); of those on
+    the busiest device, ``real_pair_compares`` = the sum over its real
+    edge slots of ``deg(u) * deg(v)`` compare two real neighbours.
+    ``deg`` is the graph's degree per global vertex id."""
+    k = int(np.argmax(prob.edge_mask.sum(axis=1)))  # the busiest device
+    u_local, v_global = prob.works[k]
+    d = np.asarray(deg, np.int64)
+    return {
+        "p": prob.p,
+        "n_rounds": prob.n_rounds,
+        "width": prob.width,
+        "e_max": prob.e_max,
+        "real_edge_slots": int(prob.edge_mask.sum()),
+        "padded_compares": prob.e_max * prob.width * prob.width,
+        "real_pair_compares": int(np.dot(d[prob.part.lo(k) + u_local],
+                                         d[v_global])),
+    }
+
+
+def _build_sharded_problem(csr, p, n_rounds, cache, width, dedup_rounds,
+                           part):
     n_rounds_requested = n_rounds
     if part is None:
         part = partition_1d(csr.n, p)

@@ -5,7 +5,9 @@
   one fixed place, so a second run of the same program on the same
   chip skips its compiles. ``JAX_COMPILATION_CACHE_DIR`` wins when it
   is set (JAX reads it itself); otherwise the cache is ``.jax_cache/``
-  at the checkout root. Call it before the first compile.
+  at the checkout root. Call it before the first compile. It also
+  starts feeding JAX's compile seconds and cache hits and misses into
+  the set-up record of ``repro.obs.trace`` (``setup_record()``).
 - ``device_summary`` names what JAX actually runs on, read from
   ``jax.devices()``, so every run states its platform.
 """
@@ -16,6 +18,8 @@ from pathlib import Path
 
 import jax
 
+from ..obs import trace as obs_trace
+
 __all__ = ["CACHE_DIR", "device_summary", "enable_compile_cache"]
 
 # src/repro/launch/chip.py -> the checkout root
@@ -25,6 +29,7 @@ CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 def enable_compile_cache() -> str:
     """Point the persistent compilation cache at its fixed directory and
     return that directory."""
+    obs_trace.install_compile_listener()
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -6,8 +6,9 @@
 Runs the compiled async engine over the first ``--p`` devices JAX
 finds: the chips of a TPU host, or host devices on the CPU (set
 XLA_FLAGS=--xla_force_host_platform_device_count=N before invoking for a
-multi-device CPU run). Prints the platform it ran on, the compile time
-and the steady wall time of one epoch, verifies exactness against the
+multi-device CPU run). Prints the platform it ran on, the compile time,
+the steady wall time of one epoch, the set-up record
+(``repro.obs.trace.setup_record``), verifies exactness against the
 single-node reference with ``--verify`` (a mismatch raises), and reports
 communication statistics + the CLaMPI-simulator view.
 """
@@ -84,8 +85,7 @@ def main(argv=None):
     jax.block_until_ready(fn(*inputs))  # compile + first epoch
     dt_first = time.perf_counter() - t0
     t0 = time.perf_counter()
-    with obs_trace.span("intersect_kernel", cat="epoch",
-                        rounds=prob.n_rounds):
+    with obs_trace.span("epoch", cat="epoch", rounds=prob.n_rounds):
         t, lcc = jax.block_until_ready(fn(*inputs))
     dt = time.perf_counter() - t0
     t, lcc = np.asarray(t), np.asarray(lcc)
@@ -93,6 +93,10 @@ def main(argv=None):
     print(f"triangles={total_t}  compile+first epoch={dt_first:.2f} s  "
           f"steady epoch wall={dt * 1e3:.1f} ms  "
           f"comm_bytes={prob.comm_bytes_per_round().sum():,}")
+    print("set-up: " + "; ".join(
+        f"{name} " + " ".join(f"{k}={v:.6g}" if isinstance(v, float)
+                              else f"{k}={v}" for k, v in entry.items())
+        for name, entry in obs_trace.setup_record().items()))
 
     if args.verify:
         from ..core.triangles import triangles_per_vertex
@@ -110,7 +114,7 @@ def main(argv=None):
             )
         print("verified exact vs single-node reference")
 
-    with obs_trace.span("delta_replay", cat="epoch"):
+    with obs_trace.span("rma_simulate", cat="epoch"):
         st = simulate_rma_lcc(
             csr, p,
             adj_cache_bytes=csr.csr_nbytes() // 4,
@@ -149,7 +153,7 @@ def main(argv=None):
                     float(prob.comm_bytes_per_round().sum()),
                     tier="wire", phase="fetch_rows")
         reg.counter("modeled_comm_s", float(st.makespan), tier="wire")
-        reg.counter("epoch_wall_s", float(dt), phase="intersect_kernel")
+        reg.counter("epoch_wall_s", float(dt), phase="epoch")
         reg.gauge("cache_get_imbalance",
                   imbalance([s.gets for s in st.adj_stats]),
                   tier="host_cache")

@@ -13,7 +13,7 @@ histogram keyed by ``(name, rank, tier, phase)``:
   ``host_cache`` (CLaMPI), ``device`` (resident tier), ``wire``
   (modeled or measured communication), ``serving`` (latency/shed)
 - ``phase`` — the span-taxonomy phase it attributes to (see
-  ``trace.PHASES``), empty when not phase-specific
+  docs/observability.md), empty when not phase-specific
 
 Adapters (``record_*``) translate the existing dataclasses verbatim —
 they never mutate the sources, so calling them twice on fresh

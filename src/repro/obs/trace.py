@@ -21,25 +21,21 @@ Design constraints, in order:
 3. **The export is a standard Chrome trace** (``{"traceEvents": [...]}``
    with ``ph: "X"`` complete events, microsecond timestamps): open it
    at https://ui.perfetto.dev or ``chrome://tracing`` unmodified.
+4. **Spans sit on the device clock.** While a tracer is installed, each
+   span also opens a ``jax.profiler.TraceAnnotation`` of its name, so a
+   profiler trace taken at the same time shows it on the host line,
+   on the same clock as the device operations.
+5. **Set-up is recorded whether or not a tracer is installed.**
+   ``setup_span()`` keeps the newest span of each name (its seconds and
+   its counts) in a small bounded record that lives as long as the
+   process; ``install_compile_listener()`` adds the seconds JAX spends
+   lowering and compiling (or loading from the persistent cache) and
+   the cache's hits and misses. Set-up runs a few times per process,
+   so this costs microseconds per run. ``setup_record()`` reads it.
+   Set-up spans never enter a ``Tracer``'s events.
 
-Taxonomy (the phase names instrumentation uses — see
-docs/observability.md for the full map):
-
-    fetch_rows        rank-indexed row transport (``ShardedRuntime``)
-    all_to_all        the SPMD collective + fused on-device intersect
-    intersect_kernel  pair-intersection compute (loop mode, streaming)
-    cache_admit       ClampiCache admission   (fine mode, instant)
-    cache_evict       ClampiCache eviction    (fine mode, instant)
-    cache_invalidate  coherence fanout through the runtime
-    residency_patch   device-tier patch/evict/admit after a batch
-    scheduler_flush   one microbatch drained through the engine
-    delta_replay      coherence replay of a delta access stream
-    stream_batch      one applied streaming update batch
-    spmd_pack         host-side packing of one SPMD execution unit
-    spmd_patch        resident-buffer drift patched to device (H2D)
-    spmd_overlap_wait the reconciliation barrier of a pipelined unit
-    pallas_kernel     one Pallas kernel dispatch (instant): which
-                      kernel, whether it ran interpreted, its shape
+The span names, the set-up spans and the compile counters are listed in
+docs/observability.md.
 
 Fine mode (``enable_tracing(fine=True)``) additionally emits per-entry
 ``cache_admit``/``cache_evict`` instants from inside the cache — useful
@@ -48,11 +44,12 @@ for cache forensics, too hot to leave on for long runs.
 from __future__ import annotations
 
 import json
+import threading
 import time
 from typing import Any, Dict, List, Optional
 
 __all__ = [
-    "PHASES",
+    "SETUP_LIMIT",
     "Tracer",
     "enable_tracing",
     "disable_tracing",
@@ -61,25 +58,10 @@ __all__ = [
     "instant",
     "counter",
     "fine_enabled",
+    "setup_span",
+    "setup_record",
+    "install_compile_listener",
 ]
-
-PHASES = (
-    "fetch_rows",
-    "all_to_all",
-    "intersect_kernel",
-    "cache_admit",
-    "cache_evict",
-    "cache_invalidate",
-    "residency_patch",
-    "scheduler_flush",
-    "delta_replay",
-    "stream_batch",
-    "spmd_pack",
-    "spmd_patch",
-    "spmd_overlap_wait",
-    "pallas_kernel",
-)
-
 
 class _NullSpan:
     """Shared no-op context manager returned when tracing is disabled."""
@@ -99,10 +81,20 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """One live span: records a ``ph: "X"`` complete event on exit."""
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``name``."""
+    from jax import profiler
 
-    __slots__ = ("_tracer", "name", "rank", "cat", "args", "_t0")
+    ann = profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+class _Span:
+    """One live span: records a ``ph: "X"`` complete event on exit, and
+    shows on the profiler's host line while it is open."""
+
+    __slots__ = ("_tracer", "name", "rank", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, rank: int, cat: str,
                  args: Optional[Dict[str, Any]]):
@@ -119,11 +111,13 @@ class _Span:
         self.args.update(args)
 
     def __enter__(self) -> "_Span":
+        self._ann = _annotation(self.name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         self._tracer._complete(self, self._t0, t1)
         return False
 
@@ -141,7 +135,6 @@ class Tracer:
         self.fine = bool(fine)
         self.events: List[dict] = []
         self._t0 = time.perf_counter()
-        self._n_dropped = 0
 
     # ---------------- recording ----------------
     def _ts(self, t: float) -> float:
@@ -305,3 +298,125 @@ def fine_enabled() -> bool:
     events were requested — the gate in the cache hot paths."""
     t = _tracer
     return t is not None and t.fine
+
+
+# --------------------------------------------------------------------------
+# Set-up record: kept whether or not a tracer is installed.
+# --------------------------------------------------------------------------
+SETUP_LIMIT = 64  # names the set-up record keeps; the oldest go first
+
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "setup.lower",
+    # a compile, or a load from the persistent compilation cache
+    "/jax/core/compile/backend_compile_duration": "setup.compile",
+}
+CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+
+
+class _SetupRecord:
+    """``{name: {"s": seconds, **counts}}``, oldest first, at most
+    ``limit`` names. ``put`` replaces a name's entry (the newest span of
+    that name); ``add`` keeps a running total."""
+
+    def __init__(self, limit: int = SETUP_LIMIT):
+        self._limit = limit
+        self._entries: Dict[str, Dict[str, Any]] = {}
+        self._lock = threading.Lock()  # compiles may finish on any thread
+
+    def _store(self, name: str, entry: Dict[str, Any]) -> None:
+        self._entries.pop(name, None)
+        self._entries[name] = entry
+        while len(self._entries) > self._limit:
+            del self._entries[next(iter(self._entries))]
+
+    def put(self, name: str, entry: Dict[str, Any]) -> None:
+        with self._lock:
+            self._store(name, entry)
+
+    def add(self, name: str, **counts: float) -> None:
+        with self._lock:
+            entry = dict(self._entries.get(name, {}))
+            for k, v in counts.items():
+                entry[k] = entry.get(k, 0) + v
+            self._store(name, entry)
+
+    def snapshot(self) -> Dict[str, Dict[str, Any]]:
+        with self._lock:
+            return {k: dict(v) for k, v in self._entries.items()}
+
+
+_setup = _SetupRecord()
+
+
+class _SetupSpan:
+    """One live set-up span: on exit, replaces its name's entry in the
+    set-up record with its seconds (``s``) and its arguments."""
+
+    __slots__ = ("name", "args", "_t0", "_ann")
+
+    def __init__(self, name: str, args: Dict[str, Any]):
+        self.name = name
+        self.args = args
+
+    def set(self, **args) -> None:
+        """Attach counts known only once the work is done."""
+        self.args.update(args)
+
+    def __enter__(self) -> "_SetupSpan":
+        self._ann = _annotation(self.name) if _tracer is not None else None
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _setup.put(self.name, {"s": dur, **{k: _jsonable(v)
+                                            for k, v in self.args.items()}})
+        return False
+
+
+def setup_span(name: str, **args) -> _SetupSpan:
+    """A context manager timing one piece of set-up into the set-up
+    record, tracer or not (``set()`` attaches counts on the way)."""
+    return _SetupSpan(name, args)
+
+
+def setup_record() -> Dict[str, Dict[str, Any]]:
+    """A copy of the set-up record: ``{name: {"s": seconds, **counts}}``
+    for the newest set-up span of each name, and ``setup.lower`` /
+    ``setup.compile`` with the process's total lowering and compile
+    seconds, their number ``n``, and (on ``setup.compile``) the
+    persistent cache's ``cache_hits`` and ``cache_misses``."""
+    return _setup.snapshot()
+
+
+def _on_compile_duration(event: str, duration: float, **_kw) -> None:
+    name = COMPILE_EVENTS.get(event)
+    if name is not None:
+        _setup.add(name, s=duration, n=1)
+
+
+def _on_cache_event(event: str, **_kw) -> None:
+    key = CACHE_EVENTS.get(event)
+    if key is not None:
+        _setup.add("setup.compile", **{key: 1})
+
+
+_compile_listener_installed = False
+
+
+def install_compile_listener() -> None:
+    """Feed JAX's compile timings and cache events into the set-up
+    record, from now on; a second call does nothing."""
+    global _compile_listener_installed
+    if _compile_listener_installed:
+        return
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_compile_duration)
+    monitoring.register_event_listener(_on_cache_event)
+    _compile_listener_installed = True

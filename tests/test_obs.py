@@ -129,6 +129,133 @@ def test_fine_mode_gates_per_entry_instants():
 
 
 # ---------------------------------------------------------------------------
+# set-up record, device clock, compile listener
+# ---------------------------------------------------------------------------
+def test_setup_span_recorded_without_tracer_newest_kept_and_bounded():
+    assert obs_trace.get_tracer() is None
+    with obs_trace.setup_span("test.setup.a", n=1) as s:
+        s.set(m=np.int64(5))
+    with obs_trace.setup_span("test.setup.a", n=2):
+        pass
+    rec = obs_trace.setup_record()
+    entry = rec["test.setup.a"]
+    assert entry["n"] == 2 and "m" not in entry  # the newest span only
+    assert entry["s"] >= 0
+    assert list(rec)[-1] == "test.setup.a"
+    json.dumps(rec)  # counts are plain numbers
+    # a copy: changing it leaves the record as it was
+    rec["test.setup.a"]["n"] = 99
+    assert obs_trace.setup_record()["test.setup.a"]["n"] == 2
+
+    names = [f"test.setup.bound.{i}" for i in range(obs_trace.SETUP_LIMIT + 5)]
+    for name in names:
+        with obs_trace.setup_span(name):
+            pass
+    rec = obs_trace.setup_record()
+    assert list(rec) == names[-obs_trace.SETUP_LIMIT:]  # the oldest went
+
+
+def test_setup_spans_leave_tracer_events_unchanged():
+    tracer = obs_trace.enable_tracing()
+    with obs_trace.span("fetch_rows", rank=0, n=2):
+        with obs_trace.setup_span("test.setup.inside", n=3):
+            pass
+    with obs_trace.setup_span("test.setup.outside"):
+        pass
+    obs_trace.disable_tracing()
+    assert len(tracer) == 1
+    assert [e["name"] for e in tracer.to_chrome()["traceEvents"]
+            if e["ph"] == "X"] == ["fetch_rows"]
+    assert set(tracer.phase_totals()) == {"fetch_rows"}
+    rec = obs_trace.setup_record()
+    assert rec["test.setup.inside"]["n"] == 3 and "test.setup.outside" in rec
+
+
+def test_span_opens_trace_annotation_only_with_tracer(monkeypatch):
+    import jax
+
+    opened = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            opened.append(("exit", self.name))
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    with obs_trace.span("fetch_rows", rank=1):
+        pass
+    with obs_trace.setup_span("test.setup.quiet"):
+        pass
+    assert opened == []  # no tracer: no annotation
+
+    obs_trace.enable_tracing()
+    with obs_trace.span("stream_batch"):
+        with obs_trace.span("fetch_rows", rank=1):
+            pass
+    with obs_trace.setup_span("test.setup.loud"):
+        pass
+    obs_trace.disable_tracing()
+    assert opened == [("enter", "stream_batch"), ("enter", "fetch_rows"),
+                      ("exit", "fetch_rows"), ("exit", "stream_batch"),
+                      ("enter", "test.setup.loud"),
+                      ("exit", "test.setup.loud")]
+
+
+def test_compile_listener_counts_cache_miss_then_hit(tmp_path, monkeypatch):
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.launch.chip import enable_compile_cache
+
+    # with the variable set, enable_compile_cache leaves the config alone
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    enable_compile_cache()  # the listener is installed once
+    keys = {"jax_compilation_cache_dir": str(tmp_path),
+            "jax_persistent_cache_min_compile_time_secs": 0.0,
+            "jax_persistent_cache_min_entry_size_bytes": -1,
+            "jax_enable_compilation_cache": True}
+    old = {k: getattr(jax.config, k) for k in keys}
+
+    def make():
+        def triple_plus_one(x):
+            return x * 3 + 1
+        return triple_plus_one
+
+    def counts():
+        rec = obs_trace.setup_record()
+        lower = rec.get("setup.lower", {})
+        comp = rec.get("setup.compile", {})
+        return (lower.get("n", 0), lower.get("s", 0.0), comp.get("n", 0),
+                comp.get("cache_misses", 0), comp.get("cache_hits", 0))
+
+    x = np.arange(8, dtype=np.float32)
+    try:
+        for k, v in keys.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+        seen = [counts()]
+        for _ in range(2):  # a new function object each time: no in-memory hit
+            jax.jit(make()).lower(x).compile()
+            seen.append(counts())
+    finally:
+        for k, v in old.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    (l0, s0, c0, m0, h0), (l1, s1, c1, m1, h1), (l2, s2, c2, m2, h2) = seen
+    assert (l1 - l0, c1 - c0, m1 - m0, h1 - h0) == (1, 1, 1, 0)  # a miss
+    assert (l2 - l1, c2 - c1, m2 - m1, h2 - h1) == (1, 1, 0, 1)  # then a hit
+    assert s2 > s1 > s0
+
+
+# ---------------------------------------------------------------------------
 # metric registry
 # ---------------------------------------------------------------------------
 def test_registry_semantics_and_snapshot_roundtrip(tmp_path):
