@@ -9,8 +9,11 @@ buffering; on TPU the XLA latency-hiding scheduler turns that structural
 overlap into DMA/compute overlap.
 
 Compute per edge: gather row_u (local) and row_v (local | cache | fetch
-buffer — one combined gather), count |row_u ∩ row_v| with the regime-split
+buffer — one combined table), count |row_u ∩ row_v| with the regime-split
 intersection, and segment-accumulate into S(u). LCC follows Eq. (2).
+Edge slots come ordered by degree class (``rma.class_layout``): each
+class block gathers and compares its rows only as wide as its class,
+not at the graph's maximum degree.
 The stages carry the named scopes ``lcc.fetch``, ``lcc.gather``,
 ``lcc.count``, ``lcc.accumulate`` and ``lcc.finalize`` in the compiled
 program's op metadata, which a profiler trace shows.
@@ -26,44 +29,62 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import trace as obs_trace
-from .intersect import count_bsearch_jnp, count_pairwise_jnp, tpu_regime_rule
-from .rma import ShardedLCCProblem
+from .intersect import count_bsearch_jnp, count_equal_pairs_jnp, tpu_regime_rule
+from .rma import ShardedLCCProblem, class_layout
 
 __all__ = [
     "device_args",
     "lcc_mesh",
+    "lcc_program",
     "lcc_pipelined",
     "make_lcc_fn",
     "run_distributed_lcc",
 ]
 
 
+def _take_rows(table, idx):
+    """``table[idx[:, 0]]`` (``idx`` is ``[N, 1]``): whole rows, which the
+    TPU gathers natively (a gather of row prefixes narrower than the
+    table becomes a loop of one-row copies)."""
+    dnums = jax.lax.GatherDimensionNumbers(
+        offset_dims=(1,), collapsed_slice_dims=(0,), start_index_map=(0,)
+    )
+    return jax.lax.gather(
+        table, idx, dnums, (1, table.shape[1]),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+    )
+
+
+def _prefixes(rows, widths):
+    """``{w: rows[:, :w]}``: the row table cut to each class width."""
+    return {w: jax.lax.slice_in_dim(rows, 0, w, axis=1) for w in widths}
+
+
 def _shard_body(
     rows_ext,  # [n_loc+1, W]
     degrees,  # [n_loc]
-    edge_u,  # [E_max]
-    edge_vc,  # [E_max]
-    edge_mask,  # [E_max]
+    slot_u,  # [NR, T] local row of u per class-ordered slot
+    slot_v,  # [NR, T] combined row index of v
     serve_idx,  # [NR, p, S_max]
     cache_rows,  # [C, W]
     *,
     axis: str,
-    n_rounds: int,
-    e_chunk: int,
+    blocks: tuple,
     sentinel: int,
     method: str,
 ):
     # shard_map keeps the sharded leading axis at local size 1 — squeeze it.
     rows_ext = rows_ext[0]
     degrees = degrees[0]
-    edge_u = edge_u[0]
-    edge_vc = edge_vc[0]
-    edge_mask = edge_mask[0]
+    slot_u = slot_u[0]
+    slot_v = slot_v[0]
     serve_idx = serve_idx[0]
     n_loc_p1, w = rows_ext.shape
     n_loc = n_loc_p1 - 1
+    n_rounds, n_slots = slot_u.shape
     p = jax.lax.psum(1, axis)
     s_max = serve_idx.shape[-1]
+    base_fetch = n_loc_p1 + cache_rows.shape[0]
 
     def fetch(r):
         # rows this device serves in round r -> one a2a -> rows it needs
@@ -74,44 +95,68 @@ def _shard_body(
             )
             return got.reshape(p * s_max, w)
 
-    def count(rows_a, rows_b, deg_a, deg_b):
-        if method == "bsearch":
-            return count_bsearch_jnp(rows_a, rows_b, sentinel)
-        if method == "pairwise":
-            return count_pairwise_jnp(rows_a, rows_b, sentinel)
-        # hybrid: regime select per edge (Eq. 3 analogue)
-        use_pw = tpu_regime_rule(deg_a, deg_b, rows_b.shape[-1])
-        return jnp.where(
-            use_pw,
-            count_pairwise_jnp(rows_a, rows_b, sentinel),
-            count_bsearch_jnp(rows_a, rows_b, sentinel),
-        )
+    def v_side(rows):
+        # v rows are padded with sentinel + 1, u rows with the sentinel:
+        # no padding slot ever equals another
+        return jnp.where(rows < sentinel, rows, sentinel + 1)
 
     deg_ext = jnp.concatenate([degrees, jnp.zeros((1,), degrees.dtype)])
 
+    def count(rows_a, rows_b, eu):
+        if method == "bsearch":
+            return count_bsearch_jnp(rows_a, rows_b, sentinel)
+        pairs = count_equal_pairs_jnp(rows_a, rows_b)
+        if method == "pairwise":
+            return pairs
+        # hybrid: regime select per edge (Eq. 3 analogue)
+        deg_b = (rows_b < sentinel).sum(-1)
+        use_pw = tpu_regime_rule(deg_ext[eu[:, 0]], deg_b, rows_b.shape[-1])
+        return jnp.where(
+            use_pw, pairs, count_bsearch_jnp(rows_a, rows_b, sentinel)
+        )
+
+    u_widths = sorted({wu for wu, _, _, _ in blocks})
+    v_widths = sorted({wv for _, wv, _, _ in blocks})
+    with jax.named_scope("lcc.gather"):
+        u_tables = _prefixes(rows_ext, u_widths)
+
     def body(r, carry):
-        fetched_cur, acc = carry
+        # v_tables: the combined rows (local | cache | round r's fetch)
+        # cut to each v class width
+        v_tables, acc = carry
         # double buffering: issue next round's fetch before this round's
         # compute so the collective overlaps the intersection work.
         fetched_nxt = fetch(jnp.minimum(r + 1, n_rounds - 1))
         with jax.named_scope("lcc.gather"):
-            combined = jnp.concatenate([rows_ext, cache_rows, fetched_cur], 0)
-            eu = jax.lax.dynamic_slice(edge_u, (r * e_chunk,), (e_chunk,))
-            evc = jax.lax.dynamic_slice(edge_vc, (r * e_chunk,), (e_chunk,))
-            msk = jax.lax.dynamic_slice(edge_mask, (r * e_chunk,), (e_chunk,))
-            rows_a = rows_ext[eu]
-            rows_b = combined[evc]
-            deg_a = deg_ext[eu]
-        with jax.named_scope("lcc.count"):
-            deg_b = (rows_b < sentinel).sum(-1)
-            cnt = count(rows_a, rows_b, deg_a, deg_b)
+            su = jax.lax.dynamic_index_in_dim(slot_u, r, keepdims=False)
+            su_col = su.reshape(n_slots, 1)
+            sv_col = jax.lax.dynamic_index_in_dim(slot_v, r).reshape(n_slots, 1)
+        counts = []
+        # one static block per degree-class pair: each slot's rows are
+        # gathered and compared only as wide as its class
+        for wu, wv, lo, hi in blocks:
+            with jax.named_scope("lcc.gather"):
+                eu = jax.lax.slice_in_dim(su_col, lo, hi)
+                rows_a = _take_rows(u_tables[wu], eu)
+                rows_b = _take_rows(
+                    v_tables[wv], jax.lax.slice_in_dim(sv_col, lo, hi))
+            with jax.named_scope("lcc.count"):
+                counts.append(count(rows_a, rows_b, eu))
         with jax.named_scope("lcc.accumulate"):
-            acc = acc.at[eu].add(jnp.where(msk, cnt, 0))
-        return fetched_nxt, acc
+            # empty slots sit on the phantom row n_loc and add 0 there
+            acc = acc.at[su].add(jnp.concatenate(counts))
+        with jax.named_scope("lcc.fetch"):
+            fetched = _prefixes(v_side(fetched_nxt), v_widths)
+            v_tables = {w: jax.lax.dynamic_update_slice_in_dim(
+                v_tables[w], fetched[w], base_fetch, 0) for w in v_widths}
+        return v_tables, acc
 
     acc0 = jnp.zeros((n_loc + 1,), jnp.int32)
-    fetched0 = fetch(0)
-    _, acc = jax.lax.fori_loop(0, n_rounds, body, (fetched0, acc0))
+    with jax.named_scope("lcc.gather"):
+        v_tables0 = _prefixes(
+            v_side(jnp.concatenate([rows_ext, cache_rows, fetch(0)], 0)),
+            v_widths)
+    _, acc = jax.lax.fori_loop(0, n_rounds, body, (v_tables0, acc0))
     with jax.named_scope("lcc.finalize"):
         s = acc[:n_loc]
         t = s // 2  # undirected: each neighbor-edge seen twice in S(i)
@@ -121,21 +166,22 @@ def _shard_body(
         return t[None], lcc[None]
 
 
-def make_lcc_fn(
-    prob: ShardedLCCProblem,
+def lcc_program(
+    blocks: tuple,
     mesh: Mesh,
     *,
+    sentinel: int,
     axis: str = "dev",
     method: str = "bsearch",
 ):
-    """jit-compiled distributed LCC over ``mesh`` (1-D, axis name ``axis``)."""
-    e_chunk = prob.e_max // prob.n_rounds
+    """jit-compiled distributed LCC over ``mesh`` (1-D, axis name
+    ``axis``) for static class blocks ``((u width, v width, first
+    column, end column), ...)``; it takes the arrays of ``device_args``."""
     body = functools.partial(
         _shard_body,
         axis=axis,
-        n_rounds=prob.n_rounds,
-        e_chunk=e_chunk,
-        sentinel=prob.sentinel,
+        blocks=tuple(blocks),
+        sentinel=sentinel,
         method=method,
     )
     sharded = P(axis)
@@ -143,11 +189,24 @@ def make_lcc_fn(
     fn = jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(sharded, sharded, sharded, sharded, sharded, sharded, repl),
+        in_specs=(sharded, sharded, sharded, sharded, sharded, repl),
         out_specs=(sharded, sharded),
         check_vma=False,
     )
     return jax.jit(fn)
+
+
+def make_lcc_fn(
+    prob: ShardedLCCProblem,
+    mesh: Mesh,
+    *,
+    axis: str = "dev",
+    method: str = "bsearch",
+):
+    """The problem's epoch program: ``lcc_program`` at its degree-class
+    blocks (``rma.class_layout``)."""
+    return lcc_program(class_layout(prob).blocks, mesh,
+                       sentinel=prob.sentinel, axis=axis, method=method)
 
 
 def lcc_mesh(p: int) -> Mesh:
@@ -161,16 +220,19 @@ def lcc_mesh(p: int) -> Mesh:
 
 
 def device_args(prob: ShardedLCCProblem, mesh: Mesh, *, axis: str = "dev"):
-    """The engine's inputs, each placed straight onto its shards (rank k's
-    slice goes to device k; the cache rows are replicated), so no single
-    device stages the whole problem. Returns once they are on the
-    devices: the set-up span ``setup.place``, with the arrays' ``bytes``."""
+    """The engine's inputs: the rows, degrees, the class-ordered slots of
+    ``rma.class_layout`` and the serve lists, each placed straight onto
+    its shards (rank k's slice goes to device k), and the replicated
+    cache rows, so no single device stages the whole problem. Returns
+    once they are on the devices: the set-up span ``setup.place``, with
+    the arrays' ``bytes``."""
     sharded = NamedSharding(mesh, P(axis))
     with obs_trace.setup_span("setup.place") as span:
+        layout = class_layout(prob)
         args = tuple(
             jax.device_put(x, sharded)
-            for x in (prob.rows_ext, prob.degrees, prob.edge_u, prob.edge_vc,
-                      prob.edge_mask, prob.serve_idx)
+            for x in (prob.rows_ext, prob.degrees, layout.slot_u,
+                      layout.slot_v, prob.serve_idx)
         ) + (jax.device_put(prob.cache_rows, NamedSharding(mesh, P())),)
         jax.block_until_ready(args)
         span.set(bytes=sum(x.nbytes for x in args))

@@ -34,6 +34,7 @@ __all__ = [
     "count_pairwise_np",
     "count_bsearch_jnp",
     "count_pairwise_jnp",
+    "count_equal_pairs_jnp",
     "count_bitmap_jnp",
     "tpu_regime_rule",
     "count_hybrid_jnp",
@@ -143,6 +144,22 @@ def count_pairwise_jnp(rows_a: jnp.ndarray, rows_b: jnp.ndarray, sentinel: int):
     eq = rows_a[..., :, None] == rows_b[..., None, :]
     eq = eq & (rows_a[..., :, None] < sentinel)
     return eq.sum(axis=(-1, -2)).astype(jnp.int32)
+
+
+def count_equal_pairs_jnp(rows_a: jnp.ndarray, rows_b: jnp.ndarray):
+    """counts[e] = sum_{s,t} (A[e,s] == B[e,t]), with no padding mask: for
+    rows whose paddings never compare equal (A padded with the sentinel,
+    B with a larger id). Five ``lax`` ops, so a program with many calls
+    traces and lowers quickly, and one compare per pair on the device."""
+    nd = rows_a.ndim
+    shape = rows_a.shape + rows_b.shape[-1:]
+    on_a = tuple(range(nd))
+    eq = jax.lax.eq(
+        jax.lax.broadcast_in_dim(rows_a, shape, on_a),
+        jax.lax.broadcast_in_dim(rows_b, shape, on_a[:-1] + (nd,)),
+    )
+    return jax.lax.reduce_sum(
+        jax.lax.convert_element_type(eq, jnp.int32), (nd - 1, nd))
 
 
 def count_bitmap_jnp(words_a: jnp.ndarray, words_b: jnp.ndarray):

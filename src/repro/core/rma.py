@@ -33,7 +33,10 @@ from .partition import Partition1D, partition_1d
 __all__ = [
     "ShardedLCCProblem",
     "ScheduleWidthOverflow",
+    "ClassLayout",
     "build_sharded_problem",
+    "class_layout",
+    "class_widths",
     "schedule_counts",
     "assert_problems_equal",
     "RMATraceStats",
@@ -42,6 +45,12 @@ __all__ = [
 
 OFFSET_ENTRY_BYTES = 8  # (start, end) pair of int32 — paper §IV-D2
 ID_BYTES = 4
+# The narrowest degree class of the epoch program: one row of a vreg's
+# 128 lanes. Each class block adds its own gathers and compare to the
+# program (trace, lowering and load time in set-up); at Graph500 S15
+# classes from 8 up would give 99 blocks for 13% fewer compares than
+# the 35 blocks from 128 up.
+MIN_CLASS_WIDTH = 128
 
 
 class ScheduleWidthOverflow(ValueError):
@@ -343,24 +352,187 @@ def build_sharded_problem(
 def schedule_counts(prob: ShardedLCCProblem, deg: np.ndarray) -> Dict[str, int]:
     """The schedule's shape and how much of its compare work is real.
 
-    The padded all-pairs compare evaluates ``e_max * width**2`` slot
-    pairs per epoch on every device (``padded_compares``); of those on
-    the busiest device, ``real_pair_compares`` = the sum over its real
-    edge slots of ``deg(u) * deg(v)`` compare two real neighbours.
-    ``deg`` is the graph's degree per global vertex id."""
+    The epoch program compares each edge slot at its degree class
+    (``class_layout``): ``padded_compares`` is what it evaluates per
+    epoch on every device, the sum over its ``class_blocks`` of slots x
+    u width x v width; of those on the busiest device,
+    ``real_pair_compares`` = the sum over its real edge slots of
+    ``deg(u) * deg(v)`` compare two real neighbours. ``deg`` is the
+    graph's degree per global vertex id."""
     k = int(np.argmax(prob.edge_mask.sum(axis=1)))  # the busiest device
     u_local, v_global = prob.works[k]
     d = np.asarray(deg, np.int64)
+    layout = class_layout(prob)
     return {
         "p": prob.p,
         "n_rounds": prob.n_rounds,
         "width": prob.width,
         "e_max": prob.e_max,
         "real_edge_slots": int(prob.edge_mask.sum()),
-        "padded_compares": prob.e_max * prob.width * prob.width,
+        "class_blocks": len(layout.caps),
+        "padded_compares": layout.compares,
         "real_pair_compares": int(np.dot(d[prob.part.lo(k) + u_local],
                                          d[v_global])),
     }
+
+
+def class_widths(width: int) -> np.ndarray:
+    """The degree-class ladder below a row width: the powers of two from
+    ``MIN_CLASS_WIDTH`` that are narrower than ``width``, then ``width``
+    itself (the top class is never rounded up past the rows)."""
+    out = []
+    c = MIN_CLASS_WIDTH
+    while c < width:
+        out.append(c)
+        c *= 2
+    return np.array(out + [width], np.int64)
+
+
+@dataclasses.dataclass(frozen=True)
+class ClassLayout:
+    """The epoch program's edge slots, ordered by degree class.
+
+    Block ``b`` compares the first ``widths[b][0]`` columns of row u
+    with the first ``widths[b][1]`` columns of row v, for ``caps[b]``
+    slots in every round. Rows are sorted and sentinel-padded, so a
+    width at least a row's length holds all of it. In each device's
+    round ``r``, block ``b``'s slots are columns ``[offsets[b],
+    offsets[b] + caps[b])`` of ``slot_u[k, r]`` (u's local row) and
+    ``slot_v[k, r]`` (v's combined row index, as ``edge_vc``). Empty
+    slots point at the phantom row on both sides and count nothing.
+    """
+
+    widths: Tuple[Tuple[int, int], ...]
+    caps: Tuple[int, ...]
+    slot_u: np.ndarray  # [p, n_rounds, sum(caps)] int32
+    slot_v: np.ndarray  # [p, n_rounds, sum(caps)] int32
+
+    @property
+    def offsets(self) -> Tuple[int, ...]:
+        return tuple(int(x) for x in np.cumsum((0,) + self.caps)[:-1])
+
+    @property
+    def blocks(self) -> Tuple[Tuple[int, int, int, int], ...]:
+        """``(u width, v width, first column, end column)`` per block:
+        the program's static shapes."""
+        return tuple((wu, wv, lo, lo + c) for (wu, wv), lo, c
+                     in zip(self.widths, self.offsets, self.caps))
+
+    @property
+    def compares(self) -> int:
+        """Compares the program evaluates per epoch on each device."""
+        n_rounds = self.slot_u.shape[1]
+        return n_rounds * sum(c * wu * wv
+                              for (wu, wv), c in zip(self.widths, self.caps))
+
+
+def class_layout(prob: ShardedLCCProblem) -> ClassLayout:
+    """The degree-class layout of the problem's real edge slots
+    (``_class_layout``). The schedule's counts, the program and its
+    placement each ask for it, so the problem keeps the newest one with
+    copies of the arrays it came from, and makes it anew once any of
+    them has changed (``apply_delta`` changes them in place)."""
+    inputs = (prob.width, prob.n_rounds, prob.edge_u, prob.edge_vc,
+              prob.edge_mask, prob.degrees, prob.serve_idx, prob.cache_rows)
+    kept = getattr(prob, "_class_layout", None)
+    if kept is not None and all(
+            np.array_equal(a, b) for a, b in zip(kept[0], inputs)):
+        return kept[1]
+    layout = _class_layout(prob)
+    prob._class_layout = (tuple(np.copy(x) for x in inputs), layout)
+    return layout
+
+
+def _class_layout(prob: ShardedLCCProblem) -> ClassLayout:
+    """The degree-class layout of the problem's real edge slots.
+
+    Each slot gets a class pair: the narrowest widths of
+    ``class_widths(width)`` that hold deg(u) and deg(v). A block holds
+    the slots of one pair on every device, with one capacity per round,
+    the same on every device (one SPMD program). A graph whose degrees
+    all fit one class gets one block as wide as its rows.
+
+    A slot whose v row is fetched must be counted in the round that
+    fetches it; any other slot (a local or a cached v row) is free, and
+    fills whatever room its block has left in any round. So where
+    nothing is fetched (p=1) every block spreads evenly over the
+    rounds, and where everything is, each block is as wide as its
+    fullest round.
+
+    Derived from the schedule's arrays alone (``edge_u``, ``edge_vc``,
+    ``edge_mask``, the row lengths of ``degrees`` and ``cache_rows``,
+    and ``serve_idx`` for the fetched rows), with numpy group ops.
+    """
+    p, nr, n_loc = prob.p, prob.n_rounds, prob.n_loc
+    e_chunk = prob.e_max // nr
+    base_fetch = n_loc + 1 + prob.cache_rows.shape[0]
+    deg_ext = np.concatenate(
+        [prob.degrees, np.zeros((p, 1), prob.degrees.dtype)], 1)
+    cache_len = (prob.cache_rows < prob.sentinel).sum(1)
+    ladder = class_widths(prob.width)
+    nc = ladder.size
+    # the class of every row length 0..width
+    class_of = np.searchsorted(ladder, np.arange(prob.width + 1))
+
+    slots = []  # per device: (u, v combined index, round, pair, tied)
+    for k in range(p):
+        e = np.flatnonzero(prob.edge_mask[k])
+        u, vc = prob.edge_u[k, e], prob.edge_vc[k, e]
+        r = e // e_chunk
+        # v's class for every combined row in every round: local rows,
+        # cache rows, then the rows each peer serves device k that round
+        fetched = deg_ext[np.arange(p)[None, :, None],
+                          prob.serve_idx[:, :, k].transpose(1, 0, 2)]
+        static = class_of[np.concatenate([deg_ext[k], cache_len])]
+        cv = np.concatenate([np.broadcast_to(static, (nr, static.size)),
+                             class_of[fetched.reshape(nr, -1)]], 1)
+        pair = class_of[deg_ext[k]][u] * nc + cv.ravel()[r * cv.shape[1] + vc]
+        slots.append((u, vc, r, pair, vc >= base_fetch))
+
+    present = np.zeros(nc * nc, bool)
+    for s in slots:
+        present[s[3]] = True
+    present[0] |= not present.any()  # no edges: one empty block
+    pairs = np.flatnonzero(present)
+    block_of = np.cumsum(present) - 1
+    nb = pairs.size
+    tied_n = np.zeros((p, nb, nr), np.int64)  # slots tied to a round
+    total = np.zeros((p, nb), np.int64)
+    for k, (u, vc, r, pair, tied) in enumerate(slots):
+        b = block_of[pair]
+        tied_n[k] = np.bincount(b[tied] * nr + r[tied],
+                                minlength=nb * nr).reshape(nb, nr)
+        total[k] = np.bincount(b, minlength=nb)
+        slots[k] = (u, vc, r, b, tied)
+    caps = np.maximum(tied_n.max(axis=(0, 2)), -(-total.max(0) // nr))
+    caps = np.maximum(caps, 1)
+    offsets = np.concatenate([[0], np.cumsum(caps)[:-1]])
+    t_cols = int(caps.sum())
+
+    slot_u = np.full((p, nr, t_cols), n_loc, np.int32)
+    slot_v = np.full((p, nr, t_cols), n_loc, np.int32)
+    for k, (u, vc, r, b, tied) in enumerate(slots):
+        # per (block, round) group, flattened: tied slots first, then the
+        # free ones fill the room left, round by round, so the j-th free
+        # slot of block b lands where b's running room first exceeds j
+        tied_k = tied_n[k].ravel()
+        room = np.cumsum((caps[:, None] - tied_n[k]).ravel())
+        room_before = np.concatenate([[0], room[:-1]])
+        col = np.empty(u.size, np.int64)
+        col[tied] = _cumcount(b[tied] * nr + r[tied])
+        free = ~tied
+        fb = b[free]
+        want = room_before[fb * nr] + _cumcount(fb)
+        g = np.searchsorted(room, want, side="right")
+        r = r.copy()
+        r[free] = g - fb * nr
+        col[free] = tied_k[g] + want - room_before[g]
+        flat = r * t_cols + offsets[b] + col
+        slot_u[k].reshape(-1)[flat] = u
+        slot_v[k].reshape(-1)[flat] = vc
+
+    widths = tuple((int(ladder[x // nc]), int(ladder[x % nc])) for x in pairs)
+    return ClassLayout(widths, tuple(int(c) for c in caps), slot_u, slot_v)
 
 
 def _build_sharded_problem(csr, p, n_rounds, cache, width, dedup_rounds,
@@ -516,6 +688,8 @@ def _cumcount(groups: np.ndarray) -> np.ndarray:
     the given order (vectorized group cumcount)."""
     if groups.size == 0:
         return np.zeros(0, np.int64)
+    if groups.min() >= 0 and groups.max() < 1 << 16:
+        groups = groups.astype(np.uint16)  # a stable sort is a radix sort
     order = np.argsort(groups, kind="stable")
     gs = groups[order]
     starts = np.flatnonzero(np.r_[True, gs[1:] != gs[:-1]])

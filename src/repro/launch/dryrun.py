@@ -353,9 +353,9 @@ def setup_cell(arch_id: str, shape_id: str, mesh: Mesh, *, opt: bool = False):
 
 
 def _setup_lcc(cfg, mesh: Mesh, meta):
-    """The paper's own engine on a flattened mesh (extra, non-assigned)."""
-    from ..core.async_engine import make_lcc_fn
-    from ..core.rma import ShardedLCCProblem
+    """The paper's own engine on a flattened mesh (extra, non-assigned),
+    with every row in one degree class of width ``row_width``."""
+    from ..core.async_engine import lcc_program
 
     p = int(mesh.devices.size)
     flat = Mesh(mesh.devices.reshape(p), ("dev",))
@@ -363,29 +363,22 @@ def _setup_lcc(cfg, mesh: Mesh, meta):
     n_loc = -(-n // p)
     w = cfg.row_width
     e_max = _pad_to(n_loc * cfg.avg_degree, cfg.n_rounds)
-    s_max = max(e_max // cfg.n_rounds // max(p - 1, 1), 8)
-    prob = ShardedLCCProblem(
-        rows_ext=np.zeros((1,), np.int32),  # placeholder, shapes only
-        degrees=None, edge_u=None, edge_vc=None, edge_mask=None,
-        serve_idx=None, cache_rows=None,
-        n=n, p=p, width=w, n_loc=n_loc, e_max=e_max,
-        n_rounds=cfg.n_rounds, s_max=s_max,
-        cache_ids=np.zeros((cfg.cache_rows,), np.int64),
-    )
-    fn = make_lcc_fn(prob, flat, method="bsearch")
+    e_chunk = e_max // cfg.n_rounds
+    s_max = max(e_chunk // max(p - 1, 1), 8)
+    fn = lcc_program(((w, w, 0, e_chunk),), flat, sentinel=n,
+                     method="bsearch")
     c = cfg.cache_rows
     sds = (
         jax.ShapeDtypeStruct((p, n_loc + 1, w), I32),
         jax.ShapeDtypeStruct((p, n_loc), I32),
-        jax.ShapeDtypeStruct((p, e_max), I32),
-        jax.ShapeDtypeStruct((p, e_max), I32),
-        jax.ShapeDtypeStruct((p, e_max), jnp.bool_),
+        jax.ShapeDtypeStruct((p, cfg.n_rounds, e_chunk), I32),
+        jax.ShapeDtypeStruct((p, cfg.n_rounds, e_chunk), I32),
         jax.ShapeDtypeStruct((p, cfg.n_rounds, p, s_max), I32),
         jax.ShapeDtypeStruct((c, w), I32),
     )
     shards = tuple(
         NamedSharding(flat, P("dev"))
-        for _ in range(6)
+        for _ in range(5)
     ) + (NamedSharding(flat, P()),)
     meta["note"] = "paper LCC engine; flat 1D mesh over all chips"
     return fn, sds, shards, meta

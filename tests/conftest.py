@@ -23,3 +23,32 @@ def powerlaw_graph(n, avg_deg, seed=0):
     from repro.graphs.datasets import powerlaw_graph as plg
 
     return plg(n, avg_deg, seed=seed)
+
+
+def class_edge_graph(seed=0):
+    """Hubs of degree 128, 129, 256, 257 and 300 (the edges of the epoch
+    program's degree classes, 2^k and 2^k + 1, and a maximum degree that
+    is not a power of two) over random edges among the other vertices."""
+    from repro.core.csr import from_edges
+
+    r = np.random.default_rng(seed)
+    n, hubs = 640, (128, 129, 256, 257, 300)
+    rest = np.arange(len(hubs), n)
+    edges = [r.choice(rest, size=(4 * n, 2))]
+    for h, d in enumerate(hubs):
+        nbrs = r.choice(rest, size=d, replace=False)
+        edges.append(np.stack([np.full(d, h), nbrs], 1))
+    return from_edges(np.concatenate(edges), n, undirected=True)
+
+
+def star_graph(n=300):
+    """One hub joined to every other vertex, whose ring closes a triangle
+    with the hub at every leaf edge."""
+    from repro.core.csr import from_edges
+
+    leaves = np.arange(1, n)
+    edges = np.concatenate([
+        np.stack([np.zeros_like(leaves), leaves], 1),
+        np.stack([leaves, np.roll(leaves, 1)], 1),
+    ])
+    return from_edges(edges, n, undirected=True)

@@ -11,7 +11,9 @@ ships with JAX, so a kernel the chip would refuse fails here:
 - ``resident_intersect`` in both layouts (vs packed rows, vs slots);
 - the SPMD serve and pair programs through ``jax.shard_map`` on the
   four-chip mesh;
-- the static epoch engine ``make_lcc_fn`` on one chip at R-MAT S12.
+- the static epoch engine ``make_lcc_fn`` on one chip at R-MAT S12, its
+  class-ordered ``pairwise`` program against one padded to the full
+  row width.
 
 Nothing runs, so these say nothing about results or times. The topology
 is described inside a fixture (never at import), so the other tests of
@@ -142,6 +144,20 @@ def test_spmd_programs_compile_on_2x2_mesh(mesh4):
     assert _has_kernel(pairs)
 
 
+def _engine_args(prob, mesh):
+    """Shapes of the epoch program's arguments (``device_args``)."""
+    from repro.core.rma import class_layout
+
+    layout = class_layout(prob)
+    sharded = NamedSharding(mesh, P("dev"))
+    return [
+        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharded)
+        for x in (prob.rows_ext, prob.degrees, layout.slot_u, layout.slot_v,
+                  prob.serve_idx)
+    ] + [jax.ShapeDtypeStruct(prob.cache_rows.shape, prob.cache_rows.dtype,
+                              sharding=NamedSharding(mesh, P()))]
+
+
 def test_static_engine_compiles_on_one_chip(topo):
     from repro.core.async_engine import make_lcc_fn
     from repro.core.rma import build_sharded_problem
@@ -149,14 +165,34 @@ def test_static_engine_compiles_on_one_chip(topo):
 
     prob = build_sharded_problem(rmat_graph(12, 16, seed=0), 1, n_rounds=4)
     mesh = Mesh(np.array(topo.devices[:1]), ("dev",))
-    sharded = NamedSharding(mesh, P("dev"))
-    args = [
-        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharded)
-        for x in (prob.rows_ext, prob.degrees, prob.edge_u, prob.edge_vc,
-                  prob.edge_mask, prob.serve_idx)
-    ] + [jax.ShapeDtypeStruct(prob.cache_rows.shape, prob.cache_rows.dtype,
-                              sharding=NamedSharding(mesh, P()))]
-    compiled = make_lcc_fn(prob, mesh, method="hybrid").lower(*args).compile()
+    compiled = make_lcc_fn(prob, mesh, method="hybrid").lower(
+        *_engine_args(prob, mesh)).compile()
     mem = compiled.memory_analysis()
     # the whole epoch must fit one v5e chip's 16 GB of HBM
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+def test_class_ordered_pairwise_needs_less_than_padded(topo, monkeypatch):
+    """The ``pairwise`` epoch program gathers and compares each edge slot
+    at its degree class; with every slot at the full row width (a ladder
+    of one class, the program before degree classes) the same graph's
+    program needs more temporary memory."""
+    from repro.core import rma
+    from repro.core.async_engine import make_lcc_fn
+    from repro.graphs.rmat import rmat_graph
+
+    csr = rmat_graph(12, 16, seed=0)
+    mesh = Mesh(np.array(topo.devices[:1]), ("dev",))
+
+    def temps(prob):
+        compiled = make_lcc_fn(prob, mesh, method="pairwise").lower(
+            *_engine_args(prob, mesh)).compile()
+        return compiled.memory_analysis().temp_size_in_bytes
+
+    classed = rma.build_sharded_problem(csr, 1, n_rounds=4)
+    assert len(rma.class_layout(classed).caps) > 1
+    monkeypatch.setattr(rma, "class_widths", lambda width: np.array([width]))
+    padded = rma.build_sharded_problem(csr, 1, n_rounds=4)
+    w = padded.width
+    assert rma.class_layout(padded).widths == ((w, w),)
+    assert temps(classed) < temps(padded)

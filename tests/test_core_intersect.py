@@ -34,7 +34,7 @@ def pad(a, w, sent):
     return out
 
 
-@pytest.mark.parametrize("method", ["bsearch", "pairwise"])
+@pytest.mark.parametrize("method", ["bsearch", "pairwise", "equal_pairs"])
 def test_jnp_counts_match_oracle(method):
     rng = np.random.default_rng(42)
     sent = 1000
@@ -44,12 +44,15 @@ def test_jnp_counts_match_oracle(method):
         a = sorted_unique(rng, sent, rng.integers(0, wa))
         b = sorted_unique(rng, sent, rng.integers(0, wb))
         rows_a.append(pad(a, wa, sent))
-        rows_b.append(pad(b, wb, sent))
+        # equal_pairs has no padding mask: B's padding must differ from A's
+        rows_b.append(pad(b, wb, sent + (method == "equal_pairs")))
         want.append(len(np.intersect1d(a, b)))
     rows_a = jnp.asarray(np.stack(rows_a))
     rows_b = jnp.asarray(np.stack(rows_b))
     if method == "bsearch":
         got = it.count_bsearch_jnp(rows_a, rows_b, sent)
+    elif method == "equal_pairs":
+        got = it.count_equal_pairs_jnp(rows_a, rows_b)
     else:
         got = it.count_pairwise_jnp(rows_a, rows_b, sent)
     assert np.array_equal(np.asarray(got), np.array(want))

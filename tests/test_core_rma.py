@@ -7,10 +7,13 @@ from repro.core.rma import (
     ScheduleWidthOverflow,
     assert_problems_equal,
     build_sharded_problem,
+    class_layout,
+    class_widths,
+    schedule_counts,
     simulate_rma_lcc,
 )
 from repro.core.partition import partition_1d
-from conftest import random_graph, powerlaw_graph
+from conftest import class_edge_graph, powerlaw_graph, random_graph, star_graph
 
 
 def resolve_rows(prob, k):
@@ -333,3 +336,124 @@ def test_maintain_schedule_refreshes_residency_without_rebuild():
     # unchanged set does not count as a refresh
     assert rt.maintain_schedule(z, z, new_cache_ids=new_ids) is True
     assert rt.schedule_residency_refreshes == 1
+
+
+# --------------------------------------------------------------------------
+# Degree-class layout of the epoch program.
+# --------------------------------------------------------------------------
+def _combined_rows(prob, k, r):
+    """Device k's combined rows in round r: local, cache, fetched."""
+    fetched = np.concatenate(
+        [prob.rows_ext[q][prob.serve_idx[q, r, k]] for q in range(prob.p)])
+    return np.concatenate([prob.rows_ext[k], prob.cache_rows, fetched])
+
+
+def test_class_widths_ladder():
+    assert class_widths(5977).tolist() == [128, 256, 512, 1024, 2048, 4096,
+                                           5977]
+    assert class_widths(4096).tolist() == [128, 256, 512, 1024, 2048, 4096]
+    assert class_widths(129).tolist() == [128, 129]
+    assert class_widths(128).tolist() == [128]
+    assert class_widths(37).tolist() == [37]
+
+
+GRAPHS = {
+    "class_edges": class_edge_graph,
+    "star": star_graph,
+    "powerlaw": lambda: powerlaw_graph(96, 6, seed=4),
+}
+
+
+@pytest.mark.parametrize("graph", sorted(GRAPHS))
+@pytest.mark.parametrize("p,cache_rows,n_rounds", [
+    (1, 0, 3), (4, 0, 2), (4, 16, 3),
+])
+def test_class_layout_places_every_real_slot_once(graph, p, cache_rows,
+                                                  n_rounds):
+    """Every real edge slot sits in exactly one block, as wide as both
+    its rows or wider (the narrowest class that holds each), a fetched
+    slot in the round that fetches its row; resolving the slots on the
+    host at their blocks' widths gives the exact triangle counts; the
+    evaluated compares are what ``schedule_counts`` reports."""
+    from repro.core.triangles import triangles_per_vertex
+
+    csr = GRAPHS[graph]()
+    cache = (build_static_degree_cache(csr.degrees, cache_rows)
+             if cache_rows else None)
+    prob = build_sharded_problem(csr, p, n_rounds=n_rounds, cache=cache)
+    lay = class_layout(prob)
+    ladder = class_widths(prob.width)
+    assert max(max(w) for w in lay.widths) <= prob.width
+    assert all(wu in ladder and wv in ladder for wu, wv in lay.widths)
+    assert len(set(lay.widths)) == len(lay.widths)
+    counts = schedule_counts(prob, csr.degrees)
+    assert counts["class_blocks"] == len(lay.caps)
+    assert counts["padded_compares"] == lay.compares
+    assert lay.compares <= prob.e_max * prob.width ** 2
+    assert lay.slot_u.shape == (p, prob.n_rounds, sum(lay.caps))
+
+    sent, e_chunk = prob.sentinel, prob.e_max // prob.n_rounds
+    base_fetch = prob.n_loc + 1 + prob.cache_rows.shape[0]
+    part = partition_1d(csr.n, p)
+    want_t = triangles_per_vertex(csr)
+    for k in range(p):
+        real = np.flatnonzero(prob.edge_mask[k])
+        want = sorted(zip(prob.edge_u[k, real].tolist(),
+                          prob.edge_vc[k, real].tolist(),
+                          (real // e_chunk).tolist()))
+        got = []
+        s = np.zeros(prob.n_loc + 1, np.int64)
+        for r in range(prob.n_rounds):
+            combined = _combined_rows(prob, k, r)
+            for wu, wv, lo, hi in lay.blocks:
+                for u, vc in zip(lay.slot_u[k, r, lo:hi],
+                                 lay.slot_v[k, r, lo:hi]):
+                    if u == prob.n_loc:  # an empty slot
+                        assert vc == prob.n_loc
+                        continue
+                    got.append((int(u), int(vc), r))
+                    row_u, row_v = prob.rows_ext[k][u], combined[vc]
+                    du, dv = (row_u < sent).sum(), (row_v < sent).sum()
+                    assert du <= wu and dv <= wv
+                    # the narrowest class that holds the row
+                    assert wu == ladder[np.searchsorted(ladder, du)]
+                    assert wv == ladder[np.searchsorted(ladder, dv)]
+                    a, b = row_u[:wu], row_v[:wv]
+                    s[u] += np.intersect1d(a[a < sent], b[b < sent]).size
+        # each real slot once; a fetched one in its own round, any other
+        # in any round
+        def placed(slot):
+            u, vc, r = slot
+            return (u, vc, r if vc >= base_fetch else -1)
+
+        assert sorted(map(placed, got)) == sorted(map(placed, want))
+        lo, hi = part.lo(k), part.hi(k)
+        assert np.array_equal(s[: hi - lo] // 2, want_t[lo:hi])
+
+
+def test_class_layout_of_a_regular_graph_is_one_padded_block():
+    """Degrees all in one class: one block as wide as the rows, the
+    padded all-pairs compare of every slot."""
+    n, k = 400, 90  # circulant graph, every degree 180
+    ring = np.arange(n)
+    edges = np.concatenate([np.stack([ring, (ring + d) % n], 1)
+                            for d in range(1, k + 1)])
+    prob = build_sharded_problem(from_edges(edges, n), 1, n_rounds=4)
+    lay = class_layout(prob)
+    assert prob.width == 2 * k
+    assert lay.widths == ((2 * k, 2 * k),)
+    assert lay.compares == prob.e_max * prob.width ** 2
+
+
+def test_class_layout_follows_changes_made_in_place():
+    """The layout kept on the problem is made anew once an array it came
+    from changes in place."""
+    csr = class_edge_graph(seed=1)
+    prob = build_sharded_problem(csr, 1, n_rounds=2)
+    full = class_layout(prob)
+    assert class_layout(prob) is full
+    prob.edge_mask[:, prob.e_max // 2:] = False
+    half = class_layout(prob)
+    assert half is not full
+    real = (half.slot_u != prob.n_loc).sum()
+    assert real == prob.edge_mask.sum() < (full.slot_u != prob.n_loc).sum()

@@ -12,9 +12,11 @@ import sys
 import numpy as np
 import pytest
 
-from conftest import powerlaw_graph, random_graph
+from conftest import class_edge_graph, powerlaw_graph, random_graph, star_graph
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+TESTS = os.path.dirname(os.path.abspath(__file__))
+SRC = os.path.join(TESTS, "..", "src")
+METHODS = ("pairwise", "bsearch", "hybrid")
 
 
 def test_engine_p1_matches_reference():
@@ -34,6 +36,20 @@ def test_engine_p1_hybrid_matches():
     csr = random_graph(64, 8, seed=1)
     t, _ = run_distributed_lcc(csr, 1, n_rounds=1, method="hybrid")
     assert np.array_equal(t, triangles_per_vertex(csr))
+
+
+@pytest.mark.parametrize("graph", [class_edge_graph, star_graph])
+@pytest.mark.parametrize("method", METHODS)
+def test_engine_p1_degree_classes_exact(method, graph):
+    """Hubs at the edges of the degree classes, a maximum degree that is
+    not a power of two, a star: exact for every method."""
+    from repro.core.async_engine import run_distributed_lcc
+    from repro.core.triangles import lcc_scores, triangles_per_vertex
+
+    csr = graph()
+    t, lcc = run_distributed_lcc(csr, 1, n_rounds=3, method=method)
+    assert np.array_equal(t, triangles_per_vertex(csr))
+    np.testing.assert_allclose(lcc, lcc_scores(csr), rtol=1e-5)
 
 
 MULTIDEV_SCRIPT = r"""
@@ -66,6 +82,19 @@ for p in (2, 4, 8):
 t, _ = run_distributed_lcc(csr, 4, n_rounds=2, cache_rows=8, method="hybrid")
 out["hybrid_ok"] = bool(np.array_equal(t, want_t))
 
+# every method on graphs with several degree classes, rows fetched and
+# cached
+import sys
+sys.path.insert(0, TESTS)
+from conftest import class_edge_graph, star_graph
+for name, g in (("class_edges", class_edge_graph()), ("star", star_graph())):
+    for method in ("pairwise", "bsearch", "hybrid"):
+        t, lcc = run_distributed_lcc(g, 4, n_rounds=3, cache_rows=16,
+                                     method=method)
+        out[f"classes_{method}_{name}_ok"] = bool(
+            np.array_equal(t, triangles_per_vertex(g))
+            and np.allclose(lcc, lcc_scores(g), rtol=1e-5))
+
 # TriC BSP baseline must also be exact
 t2, lcc2 = tric_lcc_jnp(csr, 4)
 part = partition_1d(csr.n, 4)
@@ -81,7 +110,7 @@ def multidev_results():
     env["PYTHONPATH"] = SRC
     env.pop("XLA_FLAGS", None)
     r = subprocess.run(
-        [sys.executable, "-c", MULTIDEV_SCRIPT],
+        [sys.executable, "-c", f"TESTS = {TESTS!r}\n" + MULTIDEV_SCRIPT],
         capture_output=True,
         text=True,
         env=env,
@@ -94,3 +123,11 @@ def multidev_results():
 def test_multidevice_exact(multidev_results):
     for k, v in multidev_results.items():
         assert v, f"{k} failed"
+
+
+@pytest.mark.parametrize("method", METHODS)
+def test_multidevice_degree_classes_exact(multidev_results, method):
+    """p=4 with cache rows: exact for every method on class-edge hubs and
+    on a star."""
+    for name in ("class_edges", "star"):
+        assert multidev_results[f"classes_{method}_{name}_ok"], name
