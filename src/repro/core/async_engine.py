@@ -3,17 +3,21 @@
 ``shard_map`` over a device axis ``"dev"`` of size p. Each device owns a
 1D partition; per round one ``all_to_all`` ships exactly the adjacency
 rows the static pull schedule (``rma.build_sharded_problem``) resolved as
-remote+uncached. The ``lax.fori_loop`` carries next-round rows so round
-``r``'s intersection overlaps round ``r+1``'s fetch — the paper's double
-buffering; on TPU the XLA latency-hiding scheduler turns that structural
-overlap into DMA/compute overlap.
+remote+uncached, each at its degree class's width, packed into one
+buffer (``rma.class_layout``); where nothing is fetched (p=1) there is
+no exchange. Round ``r+1``'s exchange is issued before round ``r``'s
+compare, so the collective overlaps the intersection work — the paper's
+double buffering; on TPU the XLA latency-hiding scheduler turns that
+structural overlap into DMA/compute overlap. The last round is peeled
+and issues no exchange.
 
-Compute per edge: gather row_u (local) and row_v (local | cache | fetch
-buffer — one combined table), count |row_u ∩ row_v| with the regime-split
-intersection, and segment-accumulate into S(u). LCC follows Eq. (2).
-Edge slots come ordered by degree class (``rma.class_layout``): each
-class block gathers and compares its rows only as wide as its class,
-not at the graph's maximum degree.
+Compute per edge: gather row_u (local) and row_v from the table of v's
+degree class (its local and cache rows, built once, then, for a class
+that is fetched, the round's fetched rows), count |row_u ∩ row_v| with
+the regime-split intersection, and segment-accumulate into S(u). LCC
+follows Eq. (2). Edge slots come ordered by degree class: each class
+block gathers and compares its rows only as wide as its class, not at
+the graph's maximum degree.
 The stages carry the named scopes ``lcc.fetch``, ``lcc.gather``,
 ``lcc.count``, ``lcc.accumulate`` and ``lcc.finalize`` in the compiled
 program's op metadata, which a profiler trace shows.
@@ -64,12 +68,13 @@ def _shard_body(
     rows_ext,  # [n_loc+1, W]
     degrees,  # [n_loc]
     slot_u,  # [NR, T] local row of u per class-ordered slot
-    slot_v,  # [NR, T] combined row index of v
-    serve_idx,  # [NR, p, S_max]
+    slot_v,  # [NR, T] row of v in the table of its class
+    fetch_idx,  # [NR, p, sum of fetch caps] local rows to send, by class
     cache_rows,  # [C, W]
     *,
     axis: str,
     blocks: tuple,
+    fetch: tuple,
     sentinel: int,
     method: str,
 ):
@@ -78,22 +83,11 @@ def _shard_body(
     degrees = degrees[0]
     slot_u = slot_u[0]
     slot_v = slot_v[0]
-    serve_idx = serve_idx[0]
-    n_loc_p1, w = rows_ext.shape
-    n_loc = n_loc_p1 - 1
-    n_rounds, n_slots = slot_u.shape
-    p = jax.lax.psum(1, axis)
-    s_max = serve_idx.shape[-1]
-    base_fetch = n_loc_p1 + cache_rows.shape[0]
-
-    def fetch(r):
-        # rows this device serves in round r -> one a2a -> rows it needs
-        with jax.named_scope("lcc.fetch"):
-            to_send = rows_ext[serve_idx[r]]  # [p, S_max, W]
-            got = jax.lax.all_to_all(
-                to_send, axis, split_axis=0, concat_axis=0, tiled=False
-            )
-            return got.reshape(p * s_max, w)
+    fetch_idx = fetch_idx[0]
+    n_loc = rows_ext.shape[0] - 1
+    n_slots = slot_u.shape[1]
+    n_rounds, p = fetch_idx.shape[:2]
+    base_fetch = n_loc + 1 + cache_rows.shape[0]
 
     def v_side(rows):
         # v rows are padded with sentinel + 1, u rows with the sentinel:
@@ -115,18 +109,41 @@ def _shard_body(
             use_pw, pairs, count_bsearch_jnp(rows_a, rows_b, sentinel)
         )
 
-    u_widths = sorted({wu for wu, _, _, _ in blocks})
+    u_widths = {wu for wu, _, _, _ in blocks} | {wc for wc, _ in fetch}
     v_widths = sorted({wv for _, wv, _, _ in blocks})
     with jax.named_scope("lcc.gather"):
-        u_tables = _prefixes(rows_ext, u_widths)
+        u_tables = _prefixes(rows_ext, sorted(u_widths))
+        # the local and cache rows of every v class, the same each round
+        v_tables = _prefixes(
+            v_side(jnp.concatenate([rows_ext, cache_rows], 0)), v_widths)
 
-    def body(r, carry):
-        # v_tables: the combined rows (local | cache | round r's fetch)
-        # cut to each v class width
-        v_tables, acc = carry
-        # double buffering: issue next round's fetch before this round's
-        # compute so the collective overlaps the intersection work.
-        fetched_nxt = fetch(jnp.minimum(r + 1, n_rounds - 1))
+    def exchange(r):
+        # the rows this device serves in round r, each at its class
+        # width, packed into one buffer -> one a2a -> each class's rows
+        # from every peer
+        with jax.named_scope("lcc.fetch"):
+            idx = jax.lax.dynamic_index_in_dim(fetch_idx, r, keepdims=False)
+            packed, lo = [], 0
+            for wc, sc in fetch:
+                rows = _take_rows(u_tables[wc], jax.lax.slice_in_dim(
+                    idx, lo, lo + sc, axis=1).reshape(p * sc, 1))
+                packed.append(rows.reshape(p, sc * wc))
+                lo += sc
+            got = jax.lax.all_to_all(jnp.concatenate(packed, 1), axis,
+                                     split_axis=0, concat_axis=0, tiled=False)
+            out, lo = {}, 0
+            for wc, sc in fetch:
+                out[wc] = v_side(got[:, lo:lo + sc * wc].reshape(p * sc, wc))
+                lo += sc * wc
+            return out
+
+    def hold(fetched, rows):
+        # a round's fetched rows go after their class's local and cache rows
+        with jax.named_scope("lcc.fetch"):
+            return {wc: jax.lax.dynamic_update_slice_in_dim(
+                fetched[wc], rows[wc], base_fetch, 0) for wc in fetched}
+
+    def compare(r, tables, acc):
         with jax.named_scope("lcc.gather"):
             su = jax.lax.dynamic_index_in_dim(slot_u, r, keepdims=False)
             su_col = su.reshape(n_slots, 1)
@@ -139,24 +156,36 @@ def _shard_body(
                 eu = jax.lax.slice_in_dim(su_col, lo, hi)
                 rows_a = _take_rows(u_tables[wu], eu)
                 rows_b = _take_rows(
-                    v_tables[wv], jax.lax.slice_in_dim(sv_col, lo, hi))
+                    tables[wv], jax.lax.slice_in_dim(sv_col, lo, hi))
             with jax.named_scope("lcc.count"):
                 counts.append(count(rows_a, rows_b, eu))
         with jax.named_scope("lcc.accumulate"):
             # empty slots sit on the phantom row n_loc and add 0 there
-            acc = acc.at[su].add(jnp.concatenate(counts))
-        with jax.named_scope("lcc.fetch"):
-            fetched = _prefixes(v_side(fetched_nxt), v_widths)
-            v_tables = {w: jax.lax.dynamic_update_slice_in_dim(
-                v_tables[w], fetched[w], base_fetch, 0) for w in v_widths}
-        return v_tables, acc
+            return acc.at[su].add(jnp.concatenate(counts))
 
-    acc0 = jnp.zeros((n_loc + 1,), jnp.int32)
-    with jax.named_scope("lcc.gather"):
-        v_tables0 = _prefixes(
-            v_side(jnp.concatenate([rows_ext, cache_rows, fetch(0)], 0)),
-            v_widths)
-    _, acc = jax.lax.fori_loop(0, n_rounds, body, (v_tables0, acc0))
+    acc = jnp.zeros((n_loc + 1,), jnp.int32)
+    if not fetch:  # nothing is fetched (p=1): no exchange
+        acc = jax.lax.fori_loop(
+            0, n_rounds, lambda r, acc: compare(r, v_tables, acc), acc)
+    else:
+        # double buffering: round r+1's exchange is issued before round
+        # r's compare, so the collective overlaps it; the last round is
+        # peeled and issues none, n_rounds exchanges in all
+        def body(r, carry):
+            fetched, acc = carry
+            nxt = exchange(r + 1)
+            acc = compare(r, {**v_tables, **fetched}, acc)
+            return hold(fetched, nxt), acc
+
+        # a fetched class's table holds the round's rows after its local
+        # and cache rows
+        first = exchange(0)
+        fetched = {wc: jnp.concatenate([v_tables.pop(wc), first[wc]])
+                   for wc in first}
+        if n_rounds > 1:
+            fetched, acc = jax.lax.fori_loop(
+                0, n_rounds - 1, body, (fetched, acc))
+        acc = compare(n_rounds - 1, {**v_tables, **fetched}, acc)
     with jax.named_scope("lcc.finalize"):
         s = acc[:n_loc]
         t = s // 2  # undirected: each neighbor-edge seen twice in S(i)
@@ -171,16 +200,20 @@ def lcc_program(
     mesh: Mesh,
     *,
     sentinel: int,
+    fetch: tuple = (),
     axis: str = "dev",
     method: str = "bsearch",
 ):
     """jit-compiled distributed LCC over ``mesh`` (1-D, axis name
     ``axis``) for static class blocks ``((u width, v width, first
-    column, end column), ...)``; it takes the arrays of ``device_args``."""
+    column, end column), ...)`` and fetched classes ``((width, rows
+    per peer), ...)`` (``rma.ClassLayout``; none: no exchange); it
+    takes the arrays of ``device_args``."""
     body = functools.partial(
         _shard_body,
         axis=axis,
         blocks=tuple(blocks),
+        fetch=tuple(fetch),
         sentinel=sentinel,
         method=method,
     )
@@ -204,9 +237,10 @@ def make_lcc_fn(
     method: str = "bsearch",
 ):
     """The problem's epoch program: ``lcc_program`` at its degree-class
-    blocks (``rma.class_layout``)."""
-    return lcc_program(class_layout(prob).blocks, mesh,
-                       sentinel=prob.sentinel, axis=axis, method=method)
+    blocks and fetched classes (``rma.class_layout``)."""
+    layout = class_layout(prob)
+    return lcc_program(layout.blocks, mesh, sentinel=prob.sentinel,
+                       fetch=layout.fetch, axis=axis, method=method)
 
 
 def lcc_mesh(p: int) -> Mesh:
@@ -220,10 +254,10 @@ def lcc_mesh(p: int) -> Mesh:
 
 
 def device_args(prob: ShardedLCCProblem, mesh: Mesh, *, axis: str = "dev"):
-    """The engine's inputs: the rows, degrees, the class-ordered slots of
-    ``rma.class_layout`` and the serve lists, each placed straight onto
-    its shards (rank k's slice goes to device k), and the replicated
-    cache rows, so no single device stages the whole problem. Returns
+    """The engine's inputs: the rows, degrees, the class-ordered slots
+    and the per-class serve lists of ``rma.class_layout``, each placed
+    straight onto its shards (rank k's slice goes to device k), and the
+    replicated cache rows, so no single device stages the whole problem. Returns
     once they are on the devices: the set-up span ``setup.place``, with
     the arrays' ``bytes``."""
     sharded = NamedSharding(mesh, P(axis))
@@ -232,7 +266,7 @@ def device_args(prob: ShardedLCCProblem, mesh: Mesh, *, axis: str = "dev"):
         args = tuple(
             jax.device_put(x, sharded)
             for x in (prob.rows_ext, prob.degrees, layout.slot_u,
-                      layout.slot_v, prob.serve_idx)
+                      layout.slot_v, layout.fetch_idx)
         ) + (jax.device_put(prob.cache_rows, NamedSharding(mesh, P())),)
         jax.block_until_ready(args)
         span.set(bytes=sum(x.nbytes for x in args))

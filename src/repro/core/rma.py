@@ -8,9 +8,9 @@ remote-read pattern is compiled into a **static pull schedule**:
   remote endpoint against the static degree cache, dedups within a round
   (the within-epoch reuse CLaMPI also captures), and emits, per round, a
   *serve list*: which of its local rows each device must ship to each peer.
-- Device-side, one ``all_to_all`` per round moves exactly those rows; the
-  pipelined engine overlaps round ``r``'s intersection with round
-  ``r+1``'s fetch (the paper's double buffering, §III-A).
+- Device-side, one ``all_to_all`` per round moves exactly those rows,
+  each at its degree class's width (``class_layout``); the engine's
+  compare blocks that read no fetched row need not wait for it.
 
 This module builds the schedule + stacked device arrays; the compiled
 engine lives in ``async_engine.py``. A host-level trace simulator
@@ -350,7 +350,8 @@ def build_sharded_problem(
 
 
 def schedule_counts(prob: ShardedLCCProblem, deg: np.ndarray) -> Dict[str, int]:
-    """The schedule's shape and how much of its compare work is real.
+    """The schedule's shape, how much of its compare work is real, and
+    what its exchanges carry.
 
     The epoch program compares each edge slot at its degree class
     (``class_layout``): ``padded_compares`` is what it evaluates per
@@ -358,11 +359,23 @@ def schedule_counts(prob: ShardedLCCProblem, deg: np.ndarray) -> Dict[str, int]:
     u width x v width; of those on the busiest device,
     ``real_pair_compares`` = the sum over its real edge slots of
     ``deg(u) * deg(v)`` compare two real neighbours. ``deg`` is the
-    graph's degree per global vertex id."""
+    graph's degree per global vertex id.
+
+    Per epoch on the busiest device: ``wire_bytes`` its exchanges move
+    off the chip, padding included (each round ships every peer its
+    class-width rows, ``class_layout(prob).exchange_ids`` ids);
+    ``payload_bytes`` the real neighbour ids in them; ``remote_reads``
+    its edge slots whose v row another device owns, ``cache_reads`` of
+    those the ones the cache rows serve; ``fetched_rows`` the rows it
+    receives."""
     k = int(np.argmax(prob.edge_mask.sum(axis=1)))  # the busiest device
     u_local, v_global = prob.works[k]
     d = np.asarray(deg, np.int64)
     layout = class_layout(prob)
+    peers = np.arange(prob.p) != k
+    sent = prob.serve_idx[k][:, peers]
+    remote = prob.part.owner(v_global) != k
+    cached = np.isin(v_global[remote], prob.cache_ids)
     return {
         "p": prob.p,
         "n_rounds": prob.n_rounds,
@@ -373,6 +386,13 @@ def schedule_counts(prob: ShardedLCCProblem, deg: np.ndarray) -> Dict[str, int]:
         "padded_compares": layout.compares,
         "real_pair_compares": int(np.dot(d[prob.part.lo(k) + u_local],
                                          d[v_global])),
+        "wire_bytes": (prob.n_rounds * (prob.p - 1) * layout.exchange_ids
+                       * ID_BYTES),
+        "payload_bytes": int(prob.degrees[k][sent[sent < prob.n_loc]].sum(
+            dtype=np.int64)) * ID_BYTES,
+        "remote_reads": int(remote.sum()),
+        "cache_reads": int(cached.sum()),
+        "fetched_rows": int((prob.serve_idx[peers, :, k] < prob.n_loc).sum()),
     }
 
 
@@ -398,18 +418,36 @@ class ClassLayout:
     width at least a row's length holds all of it. In each device's
     round ``r``, block ``b``'s slots are columns ``[offsets[b],
     offsets[b] + caps[b])`` of ``slot_u[k, r]`` (u's local row) and
-    ``slot_v[k, r]`` (v's combined row index, as ``edge_vc``). Empty
-    slots point at the phantom row on both sides and count nothing.
+    ``slot_v[k, r]`` (v's row in the table of v's class). Empty slots
+    point at the phantom row on both sides and count nothing.
+
+    Rows are fetched and held at their degree class. Class ``c`` of
+    ``fetch`` = ``((width, cap), ...)`` ships up to ``cap`` rows
+    ``width`` wide from each device to each other device per round: the
+    local rows ``fetch_idx[q, r, k, off_c:off_c + cap]`` of sender ``q``
+    for receiver ``k`` (``off_c`` the caps of the classes before it;
+    padding is the phantom row ``n_loc``). v's row index in the table of
+    v's class is, as in ``edge_vc``, a local row below ``n_loc + 1``,
+    then the cache rows, then (classes in ``fetch`` only) ``n_loc + 1 +
+    C + q * cap + j`` for the ``j``-th row that ``q`` serves in the
+    round.
     """
 
     widths: Tuple[Tuple[int, int], ...]
     caps: Tuple[int, ...]
     slot_u: np.ndarray  # [p, n_rounds, sum(caps)] int32
     slot_v: np.ndarray  # [p, n_rounds, sum(caps)] int32
+    fetch: Tuple[Tuple[int, int], ...]  # (class width, rows per peer)
+    fetch_idx: np.ndarray  # [p, n_rounds, p, sum of fetch caps] int32
 
     @property
     def offsets(self) -> Tuple[int, ...]:
         return tuple(int(x) for x in np.cumsum((0,) + self.caps)[:-1])
+
+    @property
+    def exchange_ids(self) -> int:
+        """Ids each device ships each peer per round, padding included."""
+        return sum(w * c for w, c in self.fetch)
 
     @property
     def blocks(self) -> Tuple[Tuple[int, int, int, int], ...]:
@@ -461,7 +499,10 @@ def _class_layout(prob: ShardedLCCProblem) -> ClassLayout:
 
     Derived from the schedule's arrays alone (``edge_u``, ``edge_vc``,
     ``edge_mask``, the row lengths of ``degrees`` and ``cache_rows``,
-    and ``serve_idx`` for the fetched rows), with numpy group ops.
+    and ``serve_idx`` for the fetched rows), with numpy group ops. The
+    serve lists are split by the class of each served row, in their
+    order; each class's cap is its longest list of any sender, round
+    and receiver.
     """
     p, nr, n_loc = prob.p, prob.n_rounds, prob.n_loc
     e_chunk = prob.e_max // nr
@@ -474,20 +515,41 @@ def _class_layout(prob: ShardedLCCProblem) -> ClassLayout:
     # the class of every row length 0..width
     class_of = np.searchsorted(ladder, np.arange(prob.width + 1))
 
-    slots = []  # per device: (u, v combined index, round, pair, tied)
+    # the serve lists [sender, round, receiver, position] split by class
+    served = prob.serve_idx
+    s_max = served.shape[-1]
+    real = served < n_loc
+    served_cls = class_of[deg_ext[np.arange(p)[:, None, None, None], served]]
+    group = (np.arange(p * nr * p).reshape(p, nr, p, 1) * nc + served_cls)[real]
+    pos_in_cls = np.zeros(served.shape, np.int64)
+    pos_in_cls[real] = _cumcount(group)
+    cls_cap = np.bincount(group, minlength=p * nr * p * nc).reshape(
+        -1, nc).max(0)
+    fetched_cls = np.flatnonzero(cls_cap)
+    cls_off = np.zeros(nc, np.int64)
+    cls_off[fetched_cls] = np.cumsum(cls_cap[fetched_cls]) - cls_cap[fetched_cls]
+    fetch_idx = np.full((p, nr, p, int(cls_cap.sum())), n_loc, np.int32)
+    sq, sr, sk, _ = np.nonzero(real)
+    fetch_idx[sq, sr, sk, cls_off[served_cls[real]] + pos_in_cls[real]] = (
+        served[real])
+
+    slots = []  # per device: (u, v index in its class table, round, pair, tied)
     for k in range(p):
         e = np.flatnonzero(prob.edge_mask[k])
         u, vc = prob.edge_u[k, e], prob.edge_vc[k, e]
         r = e // e_chunk
-        # v's class for every combined row in every round: local rows,
-        # cache rows, then the rows each peer serves device k that round
-        fetched = deg_ext[np.arange(p)[None, :, None],
-                          prob.serve_idx[:, :, k].transpose(1, 0, 2)]
+        tied = vc >= base_fetch
+        # a fetched v row: sender q's s-th row for device k in round r,
+        # moved to its place in the table of its class
+        q, s = np.divmod(vc[tied] - base_fetch, s_max)
         static = class_of[np.concatenate([deg_ext[k], cache_len])]
-        cv = np.concatenate([np.broadcast_to(static, (nr, static.size)),
-                             class_of[fetched.reshape(nr, -1)]], 1)
-        pair = class_of[deg_ext[k]][u] * nc + cv.ravel()[r * cv.shape[1] + vc]
-        slots.append((u, vc, r, pair, vc >= base_fetch))
+        cv = static[np.where(tied, 0, vc)]
+        cv[tied] = served_cls[q, r[tied], k, s]
+        vc = vc.astype(np.int64)
+        vc[tied] = (base_fetch + q * cls_cap[cv[tied]]
+                    + pos_in_cls[q, r[tied], k, s])
+        pair = class_of[deg_ext[k]][u] * nc + cv
+        slots.append((u, vc, r, pair, tied))
 
     present = np.zeros(nc * nc, bool)
     for s in slots:
@@ -532,7 +594,9 @@ def _class_layout(prob: ShardedLCCProblem) -> ClassLayout:
         slot_v[k].reshape(-1)[flat] = vc
 
     widths = tuple((int(ladder[x // nc]), int(ladder[x % nc])) for x in pairs)
-    return ClassLayout(widths, tuple(int(c) for c in caps), slot_u, slot_v)
+    fetch = tuple((int(ladder[c]), int(cls_cap[c])) for c in fetched_cls)
+    return ClassLayout(widths, tuple(int(c) for c in caps), slot_u, slot_v,
+                       fetch, fetch_idx)
 
 
 def _build_sharded_problem(csr, p, n_rounds, cache, width, dedup_rounds,

@@ -13,7 +13,8 @@ ships with JAX, so a kernel the chip would refuse fails here:
   four-chip mesh;
 - the static epoch engine ``make_lcc_fn`` on one chip at R-MAT S12, its
   class-ordered ``pairwise`` program against one padded to the full
-  row width.
+  row width, and on the four-chip mesh at R-MAT S11 with cache rows,
+  its rows exchanged at their degree class.
 
 Nothing runs, so these say nothing about results or times. The topology
 is described inside a fixture (never at import), so the other tests of
@@ -153,7 +154,7 @@ def _engine_args(prob, mesh):
     return [
         jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharded)
         for x in (prob.rows_ext, prob.degrees, layout.slot_u, layout.slot_v,
-                  prob.serve_idx)
+                  layout.fetch_idx)
     ] + [jax.ShapeDtypeStruct(prob.cache_rows.shape, prob.cache_rows.dtype,
                               sharding=NamedSharding(mesh, P()))]
 
@@ -196,3 +197,30 @@ def test_class_ordered_pairwise_needs_less_than_padded(topo, monkeypatch):
     w = padded.width
     assert rma.class_layout(padded).widths == ((w, w),)
     assert temps(classed) < temps(padded)
+
+
+def test_class_width_exchange_compiles_on_2x2(topo):
+    """The p=4 epoch program with cache rows compiles for the 2x2 mesh
+    with one exchange a round (the first before the round loop, the
+    next issued inside it ahead of the compare), and no operand of them
+    is as wide as the rows: the rows cross at their degree class."""
+    import re
+
+    from repro.core.async_engine import make_lcc_fn
+    from repro.core.cache import build_static_degree_cache
+    from repro.core.rma import build_sharded_problem, class_layout
+    from repro.graphs.rmat import rmat_graph
+
+    csr = rmat_graph(11, 16, seed=0)
+    prob = build_sharded_problem(
+        csr, 4, n_rounds=2, cache=build_static_degree_cache(csr.degrees, 8))
+    assert len(class_layout(prob).fetch) == 3
+    mesh = Mesh(np.array(topo.devices), ("dev",))
+    text = make_lcc_fn(prob, mesh, method="pairwise").lower(
+        *_engine_args(prob, mesh)).compile().as_text()
+    a2a = [line for line in text.splitlines() if " all-to-all(" in line]
+    assert len(a2a) == prob.n_rounds == 2
+    dims = {int(d) for line in a2a
+            for shape in re.findall(r"s32\[([0-9,]+)\]", line)
+            for d in shape.split(",")}
+    assert dims and prob.width not in dims

@@ -341,11 +341,34 @@ def test_maintain_schedule_refreshes_residency_without_rebuild():
 # --------------------------------------------------------------------------
 # Degree-class layout of the epoch program.
 # --------------------------------------------------------------------------
-def _combined_rows(prob, k, r):
-    """Device k's combined rows in round r: local, cache, fetched."""
-    fetched = np.concatenate(
-        [prob.rows_ext[q][prob.serve_idx[q, r, k]] for q in range(prob.p)])
-    return np.concatenate([prob.rows_ext[k], prob.cache_rows, fetched])
+def _combined_ids(prob, k, r):
+    """The vertex of each of device k's combined rows in round r (as
+    ``edge_vc`` indexes them): local, cache, fetched."""
+    part = prob.part
+    fetched = [part.lo(q) + prob.serve_idx[q, r, k] for q in range(prob.p)]
+    return np.concatenate([part.lo(k) + np.arange(prob.n_loc + 1),
+                           prob.cache_ids, *fetched])
+
+
+def _class_ids(prob, lay, k, r, wv):
+    """The vertex of each row of device k's table of v class ``wv`` in
+    round r: local, cache, then (a fetched class) what each peer serves
+    k at that class (real slots index only real rows)."""
+    part = prob.part
+    ids = [part.lo(k) + np.arange(prob.n_loc + 1), prob.cache_ids]
+    lo = 0
+    for wc, sc in lay.fetch:
+        if wc == wv:
+            ids += [part.lo(q) + lay.fetch_idx[q, r, k, lo:lo + sc]
+                    for q in range(prob.p)]
+        lo += sc
+    return np.concatenate(ids)
+
+
+def _row_of(prob, vertex):
+    """The padded row of a global vertex, from its owner."""
+    q = int(prob.part.owner(vertex))
+    return prob.rows_ext[q][vertex - prob.part.lo(q)]
 
 
 def test_class_widths_ladder():
@@ -398,21 +421,25 @@ def test_class_layout_places_every_real_slot_once(graph, p, cache_rows,
     want_t = triangles_per_vertex(csr)
     for k in range(p):
         real = np.flatnonzero(prob.edge_mask[k])
-        want = sorted(zip(prob.edge_u[k, real].tolist(),
-                          prob.edge_vc[k, real].tolist(),
-                          (real // e_chunk).tolist()))
+        want = [(u, int(_combined_ids(prob, k, r)[vc]),
+                 r if vc >= base_fetch else -1)
+                for u, vc, r in zip(prob.edge_u[k, real].tolist(),
+                                    prob.edge_vc[k, real].tolist(),
+                                    (real // e_chunk).tolist())]
         got = []
         s = np.zeros(prob.n_loc + 1, np.int64)
         for r in range(prob.n_rounds):
-            combined = _combined_rows(prob, k, r)
             for wu, wv, lo, hi in lay.blocks:
+                ids = _class_ids(prob, lay, k, r, wv)
                 for u, vc in zip(lay.slot_u[k, r, lo:hi],
                                  lay.slot_v[k, r, lo:hi]):
                     if u == prob.n_loc:  # an empty slot
                         assert vc == prob.n_loc
                         continue
-                    got.append((int(u), int(vc), r))
-                    row_u, row_v = prob.rows_ext[k][u], combined[vc]
+                    got.append((int(u), int(ids[vc]),
+                                r if vc >= base_fetch else -1))
+                    row_u = prob.rows_ext[k][u]
+                    row_v = _row_of(prob, int(ids[vc]))
                     du, dv = (row_u < sent).sum(), (row_v < sent).sum()
                     assert du <= wu and dv <= wv
                     # the narrowest class that holds the row
@@ -420,13 +447,9 @@ def test_class_layout_places_every_real_slot_once(graph, p, cache_rows,
                     assert wv == ladder[np.searchsorted(ladder, dv)]
                     a, b = row_u[:wu], row_v[:wv]
                     s[u] += np.intersect1d(a[a < sent], b[b < sent]).size
-        # each real slot once; a fetched one in its own round, any other
-        # in any round
-        def placed(slot):
-            u, vc, r = slot
-            return (u, vc, r if vc >= base_fetch else -1)
-
-        assert sorted(map(placed, got)) == sorted(map(placed, want))
+        # each real slot once, reading v's row; a fetched one in its own
+        # round, any other in any round
+        assert sorted(got) == sorted(want)
         lo, hi = part.lo(k), part.hi(k)
         assert np.array_equal(s[: hi - lo] // 2, want_t[lo:hi])
 
@@ -457,3 +480,93 @@ def test_class_layout_follows_changes_made_in_place():
     assert half is not full
     real = (half.slot_u != prob.n_loc).sum()
     assert real == prob.edge_mask.sum() < (full.slot_u != prob.n_loc).sum()
+
+
+@pytest.mark.parametrize("graph", sorted(GRAPHS))
+@pytest.mark.parametrize("p,cache_rows,n_rounds", [
+    (1, 0, 3), (4, 0, 2), (4, 16, 3),
+])
+def test_class_layout_serves_each_fetched_row_once_at_its_class(
+        graph, p, cache_rows, n_rounds):
+    """The per-class serve lists hold every row of the serve lists once,
+    in the list of the class of its degree, within that class's cap; a
+    class is fetched only where some row of it is served."""
+    csr = GRAPHS[graph]()
+    cache = (build_static_degree_cache(csr.degrees, cache_rows)
+             if cache_rows else None)
+    prob = build_sharded_problem(csr, p, n_rounds=n_rounds, cache=cache)
+    lay = class_layout(prob)
+    ladder = class_widths(prob.width)
+    caps = [c for _, c in lay.fetch]
+    assert all(c > 0 for c in caps)
+    assert lay.fetch_idx.shape == (p, prob.n_rounds, p, sum(caps))
+    assert lay.exchange_ids == sum(w * c for w, c in lay.fetch)
+    seen = set()
+    for q in range(p):
+        for r in range(prob.n_rounds):
+            for k in range(p):
+                want = prob.serve_idx[q, r, k]
+                want = sorted(want[want < prob.n_loc].tolist())
+                got, lo = [], 0
+                for wc, sc in lay.fetch:
+                    rows = lay.fetch_idx[q, r, k, lo:lo + sc]
+                    rows = rows[rows < prob.n_loc]
+                    deg = prob.degrees[q, rows]
+                    assert np.all(ladder[np.searchsorted(ladder, deg)] == wc)
+                    if rows.size:
+                        seen.add(wc)
+                    got += rows.tolist()
+                    lo += sc
+                assert sorted(got) == want
+    assert seen == {wc for wc, _ in lay.fetch}
+    if p == 1:
+        assert lay.fetch == ()
+
+
+def test_schedule_counts_exchange_matches_the_edge_list():
+    """The exchange counts of ``schedule_counts`` equal those taken
+    straight from the edge list: the busiest device's remote and cached
+    reads, the rows it receives and the real ids it ships per round, and
+    the wire ids every device ships each peer (each class's longest
+    list at the class's width)."""
+    csr = powerlaw_graph(96, 6, seed=4)
+    p, nr = 4, 3
+    cache = build_static_degree_cache(csr.degrees, 8)
+    prob = build_sharded_problem(csr, p, n_rounds=nr, cache=cache)
+    got = schedule_counts(prob, csr.degrees)
+
+    part = partition_1d(csr.n, p)
+    deg = csr.degrees.astype(np.int64)
+    ladder = class_widths(int(deg.max()))
+    cached = set(cache.vertex_ids.tolist())
+    owned = [np.flatnonzero(part.owner(np.arange(csr.n)) == k)
+             for k in range(p)]
+    edges = [[(u, int(v)) for u in owned[k]
+              for v in csr.adjacencies[csr.offsets[u]:csr.offsets[u + 1]]]
+             for k in range(p)]
+    chunk = -(-max(len(e) for e in edges) // nr)
+    # want[j][r][q]: the rows device j fetches from q in round r
+    want = [[[set() for _ in range(p)] for _ in range(nr)] for _ in range(p)]
+    for j in range(p):
+        for i, (u, v) in enumerate(edges[j]):
+            q = int(part.owner(v))
+            if q != j and v not in cached:
+                want[j][i // chunk][q].add(v)
+    k = max(range(p), key=lambda j: len(edges[j]))
+    remote = [v for u, v in edges[k] if part.owner(v) != k]
+    cap = {}
+    for j in range(p):
+        for r in range(nr):
+            for q in range(p):
+                for wc in ladder:
+                    n_c = sum(ladder[np.searchsorted(ladder, deg[v])] == wc
+                              for v in want[j][r][q])
+                    cap[wc] = max(cap.get(wc, 0), n_c)
+    assert got["remote_reads"] == len(remote)
+    assert got["cache_reads"] == sum(v in cached for v in remote)
+    assert got["fetched_rows"] == sum(len(s) for r in want[k] for s in r)
+    assert got["payload_bytes"] == 4 * sum(
+        deg[v] for j in range(p) for r in range(nr) for v in want[j][r][k])
+    assert got["wire_bytes"] == 4 * nr * (p - 1) * sum(
+        wc * c for wc, c in cap.items())
+    assert got["wire_bytes"] > got["payload_bytes"] > 0
