@@ -11,13 +11,16 @@ double buffering; on TPU the XLA latency-hiding scheduler turns that
 structural overlap into DMA/compute overlap. The last round is peeled
 and issues no exchange.
 
-Compute per edge: gather row_u (local) and row_v from the table of v's
+Compute per undirected edge, once, on the owner of its lower (degree,
+id) endpoint u: gather row_u (local) and row_v from the table of v's
 degree class (its local and cache rows, built once, then, for a class
 that is fetched, the round's fetched rows), count |row_u ∩ row_v| with
-the regime-split intersection, and segment-accumulate into S(u). LCC
-follows Eq. (2). Edge slots come ordered by degree class: each class
-block gathers and compares its rows only as wide as its class, not at
-the graph's maximum degree.
+the regime-split intersection, and segment-accumulate it into both
+S(u) and S(v). Where p > 1, v's share goes to a buffer of every
+device's rows, and one ``psum_scatter`` closes the epoch by handing
+each owner its own. LCC follows Eq. (2). Edge slots come ordered by
+degree class: each class block gathers and compares its rows only as
+wide as its class, not at the graph's maximum degree.
 The stages carry the named scopes ``lcc.fetch``, ``lcc.gather``,
 ``lcc.count``, ``lcc.accumulate`` and ``lcc.finalize`` in the compiled
 program's op metadata, which a profiler trace shows.
@@ -69,6 +72,7 @@ def _shard_body(
     degrees,  # [n_loc]
     slot_u,  # [NR, T] local row of u per class-ordered slot
     slot_v,  # [NR, T] row of v in the table of its class
+    slot_acc,  # [NR, T] v's accumulator index: owner * (n_loc+1) + row
     fetch_idx,  # [NR, p, sum of fetch caps] local rows to send, by class
     cache_rows,  # [C, W]
     *,
@@ -83,6 +87,7 @@ def _shard_body(
     degrees = degrees[0]
     slot_u = slot_u[0]
     slot_v = slot_v[0]
+    slot_acc = slot_acc[0]
     fetch_idx = fetch_idx[0]
     n_loc = rows_ext.shape[0] - 1
     n_slots = slot_u.shape[1]
@@ -143,11 +148,16 @@ def _shard_body(
             return {wc: jax.lax.dynamic_update_slice_in_dim(
                 fetched[wc], rows[wc], base_fetch, 0) for wc in fetched}
 
+    # the accumulator holds every device's rows, (owner, local row), and
+    # at p > 1 is summed onto their owners at the end
+    u_base = jax.lax.axis_index(axis) * (n_loc + 1) if p > 1 else 0
+
     def compare(r, tables, acc):
         with jax.named_scope("lcc.gather"):
             su = jax.lax.dynamic_index_in_dim(slot_u, r, keepdims=False)
             su_col = su.reshape(n_slots, 1)
             sv_col = jax.lax.dynamic_index_in_dim(slot_v, r).reshape(n_slots, 1)
+            sa = jax.lax.dynamic_index_in_dim(slot_acc, r, keepdims=False)
         counts = []
         # one static block per degree-class pair: each slot's rows are
         # gathered and compared only as wide as its class
@@ -160,10 +170,12 @@ def _shard_body(
             with jax.named_scope("lcc.count"):
                 counts.append(count(rows_a, rows_b, eu))
         with jax.named_scope("lcc.accumulate"):
-            # empty slots sit on the phantom row n_loc and add 0 there
-            return acc.at[su].add(jnp.concatenate(counts))
+            # each edge is compared once: its count goes to u here and to
+            # v at its owner's row; empty slots add 0 at a phantom row
+            c = jnp.concatenate(counts)
+            return acc.at[su + u_base].add(c).at[sa].add(c)
 
-    acc = jnp.zeros((n_loc + 1,), jnp.int32)
+    acc = jnp.zeros((p * (n_loc + 1),), jnp.int32)
     if not fetch:  # nothing is fetched (p=1): no exchange
         acc = jax.lax.fori_loop(
             0, n_rounds, lambda r, acc: compare(r, v_tables, acc), acc)
@@ -186,6 +198,10 @@ def _shard_body(
             fetched, acc = jax.lax.fori_loop(
                 0, n_rounds - 1, body, (fetched, acc))
         acc = compare(n_rounds - 1, {**v_tables, **fetched}, acc)
+    if p > 1:
+        with jax.named_scope("lcc.accumulate"):
+            # each owner gets the sum of every device's adds to its rows
+            acc = jax.lax.psum_scatter(acc, axis, tiled=True)
     with jax.named_scope("lcc.finalize"):
         s = acc[:n_loc]
         t = s // 2  # undirected: each neighbor-edge seen twice in S(i)
@@ -222,7 +238,7 @@ def lcc_program(
     fn = jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(sharded, sharded, sharded, sharded, sharded, repl),
+        in_specs=(sharded,) * 6 + (repl,),
         out_specs=(sharded, sharded),
         check_vma=False,
     )
@@ -255,7 +271,8 @@ def lcc_mesh(p: int) -> Mesh:
 
 def device_args(prob: ShardedLCCProblem, mesh: Mesh, *, axis: str = "dev"):
     """The engine's inputs: the rows, degrees, the class-ordered slots
-    and the per-class serve lists of ``rma.class_layout``, each placed
+    (u's row, v's row, v's accumulator index) and the per-class serve
+    lists of ``rma.class_layout``, each placed
     straight onto its shards (rank k's slice goes to device k), and the
     replicated cache rows, so no single device stages the whole problem. Returns
     once they are on the devices: the set-up span ``setup.place``, with
@@ -266,7 +283,7 @@ def device_args(prob: ShardedLCCProblem, mesh: Mesh, *, axis: str = "dev"):
         args = tuple(
             jax.device_put(x, sharded)
             for x in (prob.rows_ext, prob.degrees, layout.slot_u,
-                      layout.slot_v, layout.fetch_idx)
+                      layout.slot_v, layout.slot_acc, layout.fetch_idx)
         ) + (jax.device_put(prob.cache_rows, NamedSharding(mesh, P())),)
         jax.block_until_ready(args)
         span.set(bytes=sum(x.nbytes for x in args))

@@ -353,46 +353,57 @@ def schedule_counts(prob: ShardedLCCProblem, deg: np.ndarray) -> Dict[str, int]:
     """The schedule's shape, how much of its compare work is real, and
     what its exchanges carry.
 
-    The epoch program compares each edge slot at its degree class
-    (``class_layout``): ``padded_compares`` is what it evaluates per
-    epoch on every device, the sum over its ``class_blocks`` of slots x
-    u width x v width; of those on the busiest device,
-    ``real_pair_compares`` = the sum over its real edge slots of
-    ``deg(u) * deg(v)`` compare two real neighbours. ``deg`` is the
-    graph's degree per global vertex id.
+    The epoch program compares each undirected edge once, at its degree
+    class (``class_layout``): ``evaluated_edge_slots`` is the slots it
+    keeps on all devices, of the ``real_edge_slots`` of the worklists
+    (both directions of every edge). ``padded_compares`` is what it
+    evaluates per epoch on every device, the sum over its
+    ``class_blocks`` of slots x u width x v width; of those on the
+    busiest device (the one with the most kept slots),
+    ``real_pair_compares`` = the sum over its kept slots of ``deg(u) *
+    deg(v)`` compare two real neighbours. ``deg`` is the graph's degree
+    per global vertex id.
 
     Per epoch on the busiest device: ``wire_bytes`` its exchanges move
     off the chip, padding included (each round ships every peer its
     class-width rows, ``class_layout(prob).exchange_ids`` ids);
     ``payload_bytes`` the real neighbour ids in them; ``remote_reads``
-    its edge slots whose v row another device owns, ``cache_reads`` of
+    its kept slots whose v row another device owns, ``cache_reads`` of
     those the ones the cache rows serve; ``fetched_rows`` the rows it
     receives."""
-    k = int(np.argmax(prob.edge_mask.sum(axis=1)))  # the busiest device
-    u_local, v_global = prob.works[k]
-    d = np.asarray(deg, np.int64)
     layout = class_layout(prob)
+    nl1 = prob.n_loc + 1
+    kept = layout.slot_u != prob.n_loc
+    k = int(np.argmax(kept.sum(axis=(1, 2))))  # the busiest device
+    mine = kept[k]
+    acc = layout.slot_acc[k][mine].astype(np.int64)
+    sv = layout.slot_v[k][mine]
+    lo_of = np.array([prob.part.lo(q) for q in range(prob.p)], np.int64)
+    d = np.asarray(deg, np.int64)
     peers = np.arange(prob.p) != k
-    sent = prob.serve_idx[k][:, peers]
-    remote = prob.part.owner(v_global) != k
-    cached = np.isin(v_global[remote], prob.cache_ids)
+    sent = layout.fetch_idx[k][:, peers]
+    remote = acc // nl1 != k
     return {
         "p": prob.p,
         "n_rounds": prob.n_rounds,
         "width": prob.width,
         "e_max": prob.e_max,
         "real_edge_slots": int(prob.edge_mask.sum()),
+        "evaluated_edge_slots": int(kept.sum()),
         "class_blocks": len(layout.caps),
         "padded_compares": layout.compares,
-        "real_pair_compares": int(np.dot(d[prob.part.lo(k) + u_local],
-                                         d[v_global])),
+        "real_pair_compares": int(np.dot(
+            d[lo_of[k] + layout.slot_u[k][mine]],
+            d[lo_of[acc // nl1] + acc % nl1])),
         "wire_bytes": (prob.n_rounds * (prob.p - 1) * layout.exchange_ids
                        * ID_BYTES),
         "payload_bytes": int(prob.degrees[k][sent[sent < prob.n_loc]].sum(
             dtype=np.int64)) * ID_BYTES,
         "remote_reads": int(remote.sum()),
-        "cache_reads": int(cached.sum()),
-        "fetched_rows": int((prob.serve_idx[peers, :, k] < prob.n_loc).sum()),
+        "cache_reads": int((remote & (sv < nl1 + prob.cache_rows.shape[0]))
+                           .sum()),
+        "fetched_rows": int((layout.fetch_idx[peers, :, k] < prob.n_loc)
+                            .sum()),
     }
 
 
@@ -412,14 +423,18 @@ def class_widths(width: int) -> np.ndarray:
 class ClassLayout:
     """The epoch program's edge slots, ordered by degree class.
 
-    Block ``b`` compares the first ``widths[b][0]`` columns of row u
-    with the first ``widths[b][1]`` columns of row v, for ``caps[b]``
-    slots in every round. Rows are sorted and sentinel-padded, so a
-    width at least a row's length holds all of it. In each device's
-    round ``r``, block ``b``'s slots are columns ``[offsets[b],
-    offsets[b] + caps[b])`` of ``slot_u[k, r]`` (u's local row) and
-    ``slot_v[k, r]`` (v's row in the table of v's class). Empty slots
-    point at the phantom row on both sides and count nothing.
+    One slot per undirected edge, on the owner of its lower (degree,
+    id) endpoint u (``_class_layout``). Block ``b`` compares the first
+    ``widths[b][0]`` columns of row u with the first ``widths[b][1]``
+    columns of row v, for ``caps[b]`` slots in every round. Rows are
+    sorted and sentinel-padded, so a width at least a row's length
+    holds all of it. In each device's round ``r``, block ``b``'s slots
+    are columns ``[offsets[b], offsets[b] + caps[b])`` of ``slot_u[k,
+    r]`` (u's local row), ``slot_v[k, r]`` (v's row in the table of v's
+    class) and ``slot_acc[k, r]`` (v's accumulator index, ``owner *
+    (n_loc + 1) + local row``: at p=1 v's local row). The count goes to
+    u and to v. Empty slots point at the phantom row (on their own
+    device for ``slot_acc``) and count nothing.
 
     Rows are fetched and held at their degree class. Class ``c`` of
     ``fetch`` = ``((width, cap), ...)`` ships up to ``cap`` rows
@@ -437,6 +452,7 @@ class ClassLayout:
     caps: Tuple[int, ...]
     slot_u: np.ndarray  # [p, n_rounds, sum(caps)] int32
     slot_v: np.ndarray  # [p, n_rounds, sum(caps)] int32
+    slot_acc: np.ndarray  # [p, n_rounds, sum(caps)] int32
     fetch: Tuple[Tuple[int, int], ...]  # (class width, rows per peer)
     fetch_idx: np.ndarray  # [p, n_rounds, p, sum of fetch caps] int32
 
@@ -471,7 +487,8 @@ def class_layout(prob: ShardedLCCProblem) -> ClassLayout:
     copies of the arrays it came from, and makes it anew once any of
     them has changed (``apply_delta`` changes them in place)."""
     inputs = (prob.width, prob.n_rounds, prob.edge_u, prob.edge_vc,
-              prob.edge_mask, prob.degrees, prob.serve_idx, prob.cache_rows)
+              prob.edge_mask, prob.degrees, prob.serve_idx, prob.cache_rows,
+              prob.cache_ids)
     kept = getattr(prob, "_class_layout", None)
     if kept is not None and all(
             np.array_equal(a, b) for a, b in zip(kept[0], inputs)):
@@ -484,11 +501,19 @@ def class_layout(prob: ShardedLCCProblem) -> ClassLayout:
 def _class_layout(prob: ShardedLCCProblem) -> ClassLayout:
     """The degree-class layout of the problem's real edge slots.
 
-    Each slot gets a class pair: the narrowest widths of
-    ``class_widths(width)`` that hold deg(u) and deg(v). A block holds
-    the slots of one pair on every device, with one capacity per round,
-    the same on every device (one SPMD program). A graph whose degrees
-    all fit one class gets one block as wide as its rows.
+    The worklists hold both directions of every undirected edge, (u, v)
+    on u's owner and (v, u) on v's; |N(u) ∩ N(v)| is symmetric, so only
+    one is compared: the slot whose u is the lower endpoint by (degree,
+    global id), and its count goes to both endpoints (``slot_acc``).
+    Reading the higher-degree row as v also makes a remote v more often
+    a cache row.
+
+    Each kept slot gets a class pair: the narrowest widths of
+    ``class_widths(width)`` that hold deg(u) and deg(v) (so the u class
+    is never the wider). A block holds the slots of one pair on every
+    device, with one capacity per round, the same on every device (one
+    SPMD program). A graph whose degrees all fit one class gets one
+    block as wide as its rows.
 
     A slot whose v row is fetched must be counted in the round that
     fetches it; any other slot (a local or a cached v row) is free, and
@@ -498,58 +523,84 @@ def _class_layout(prob: ShardedLCCProblem) -> ClassLayout:
     fullest round.
 
     Derived from the schedule's arrays alone (``edge_u``, ``edge_vc``,
-    ``edge_mask``, the row lengths of ``degrees`` and ``cache_rows``,
-    and ``serve_idx`` for the fetched rows), with numpy group ops. The
-    serve lists are split by the class of each served row, in their
-    order; each class's cap is its longest list of any sender, round
-    and receiver.
+    ``edge_mask``, ``degrees``, the cache rows and their ids, and
+    ``serve_idx`` for the fetched rows), with numpy group ops. The
+    serve lists keep the rows some kept slot reads, split by the class
+    of each row, in their order; each class's cap is its longest list
+    of any sender, round and receiver.
     """
     p, nr, n_loc = prob.p, prob.n_rounds, prob.n_loc
+    nl1 = n_loc + 1
     e_chunk = prob.e_max // nr
-    base_fetch = n_loc + 1 + prob.cache_rows.shape[0]
+    base_fetch = nl1 + prob.cache_rows.shape[0]
+    part = prob.part
+    lo_of = np.array([part.lo(k) for k in range(p)], np.int64)
     deg_ext = np.concatenate(
         [prob.degrees, np.zeros((p, 1), prob.degrees.dtype)], 1)
+    deg_flat = deg_ext.reshape(-1).astype(np.int64)
+    # a cache row's accumulator index: its vertex's (owner, local row)
+    cache_ids = np.asarray(prob.cache_ids, np.int64)
+    cache_own = part.owner(cache_ids).astype(np.int64)
+    cache_acc = cache_own * nl1 + cache_ids - lo_of[cache_own]
     cache_len = (prob.cache_rows < prob.sentinel).sum(1)
     ladder = class_widths(prob.width)
     nc = ladder.size
     # the class of every row length 0..width
     class_of = np.searchsorted(ladder, np.arange(prob.width + 1))
-
-    # the serve lists [sender, round, receiver, position] split by class
     served = prob.serve_idx
     s_max = served.shape[-1]
-    real = served < n_loc
+
+    # every device's kept slots: (u, v index, round, tied, v's
+    # accumulator index, and for the tied ones the sender and place of
+    # the served row they read, which is marked used)
+    slots = []
+    used = np.zeros(served.shape, bool)
+    for k in range(p):
+        e = np.flatnonzero(prob.edge_mask[k])
+        u = prob.edge_u[k, e].astype(np.int64)
+        vc = prob.edge_vc[k, e].astype(np.int64)
+        r = e // e_chunk
+        tied = vc >= base_fetch
+        cached = (vc > n_loc) & ~tied
+        # a fetched v row: sender q's s-th row for device k in round r
+        q, s = np.divmod(vc[tied] - base_fetch, s_max)
+        acc = k * nl1 + vc
+        acc[cached] = cache_acc[vc[cached] - nl1]
+        acc[tied] = q * nl1 + served[q, r[tied], k, s]
+        du, dv = deg_ext[k, u], deg_flat[acc]
+        gu = lo_of[k] + u
+        gv = lo_of[acc // nl1] + acc % nl1
+        keep = (du < dv) | ((du == dv) & (gu < gv))
+        kt = keep[tied]
+        used[q[kt], r[tied][kt], k, s[kt]] = True
+        slots.append((u[keep], vc[keep], r[keep], tied[keep], acc[keep],
+                      q[kt], s[kt]))
+
+    # the serve lists [sender, round, receiver, position] of the rows
+    # kept slots read, split by class
     served_cls = class_of[deg_ext[np.arange(p)[:, None, None, None], served]]
-    group = (np.arange(p * nr * p).reshape(p, nr, p, 1) * nc + served_cls)[real]
+    group = (np.arange(p * nr * p).reshape(p, nr, p, 1) * nc + served_cls)[used]
     pos_in_cls = np.zeros(served.shape, np.int64)
-    pos_in_cls[real] = _cumcount(group)
+    pos_in_cls[used] = _cumcount(group)
     cls_cap = np.bincount(group, minlength=p * nr * p * nc).reshape(
         -1, nc).max(0)
     fetched_cls = np.flatnonzero(cls_cap)
     cls_off = np.zeros(nc, np.int64)
     cls_off[fetched_cls] = np.cumsum(cls_cap[fetched_cls]) - cls_cap[fetched_cls]
     fetch_idx = np.full((p, nr, p, int(cls_cap.sum())), n_loc, np.int32)
-    sq, sr, sk, _ = np.nonzero(real)
-    fetch_idx[sq, sr, sk, cls_off[served_cls[real]] + pos_in_cls[real]] = (
-        served[real])
+    sq, sr, sk, _ = np.nonzero(used)
+    fetch_idx[sq, sr, sk, cls_off[served_cls[used]] + pos_in_cls[used]] = (
+        served[used])
 
-    slots = []  # per device: (u, v index in its class table, round, pair, tied)
-    for k in range(p):
-        e = np.flatnonzero(prob.edge_mask[k])
-        u, vc = prob.edge_u[k, e], prob.edge_vc[k, e]
-        r = e // e_chunk
-        tied = vc >= base_fetch
-        # a fetched v row: sender q's s-th row for device k in round r,
-        # moved to its place in the table of its class
-        q, s = np.divmod(vc[tied] - base_fetch, s_max)
+    for k, (u, vc, r, tied, acc, q, s) in enumerate(slots):
+        # a fetched v row moves to its place in the table of its class
         static = class_of[np.concatenate([deg_ext[k], cache_len])]
         cv = static[np.where(tied, 0, vc)]
         cv[tied] = served_cls[q, r[tied], k, s]
-        vc = vc.astype(np.int64)
         vc[tied] = (base_fetch + q * cls_cap[cv[tied]]
                     + pos_in_cls[q, r[tied], k, s])
         pair = class_of[deg_ext[k]][u] * nc + cv
-        slots.append((u, vc, r, pair, tied))
+        slots[k] = (u, vc, r, pair, tied, acc)
 
     present = np.zeros(nc * nc, bool)
     for s in slots:
@@ -560,12 +611,12 @@ def _class_layout(prob: ShardedLCCProblem) -> ClassLayout:
     nb = pairs.size
     tied_n = np.zeros((p, nb, nr), np.int64)  # slots tied to a round
     total = np.zeros((p, nb), np.int64)
-    for k, (u, vc, r, pair, tied) in enumerate(slots):
+    for k, (u, vc, r, pair, tied, acc) in enumerate(slots):
         b = block_of[pair]
         tied_n[k] = np.bincount(b[tied] * nr + r[tied],
                                 minlength=nb * nr).reshape(nb, nr)
         total[k] = np.bincount(b, minlength=nb)
-        slots[k] = (u, vc, r, b, tied)
+        slots[k] = (u, vc, r, b, tied, acc)
     caps = np.maximum(tied_n.max(axis=(0, 2)), -(-total.max(0) // nr))
     caps = np.maximum(caps, 1)
     offsets = np.concatenate([[0], np.cumsum(caps)[:-1]])
@@ -573,7 +624,10 @@ def _class_layout(prob: ShardedLCCProblem) -> ClassLayout:
 
     slot_u = np.full((p, nr, t_cols), n_loc, np.int32)
     slot_v = np.full((p, nr, t_cols), n_loc, np.int32)
-    for k, (u, vc, r, b, tied) in enumerate(slots):
+    # empty slots add 0 at their own device's phantom row
+    slot_acc = np.repeat(np.arange(p, dtype=np.int32) * nl1 + n_loc,
+                         nr * t_cols).reshape(p, nr, t_cols)
+    for k, (u, vc, r, b, tied, acc) in enumerate(slots):
         # per (block, round) group, flattened: tied slots first, then the
         # free ones fill the room left, round by round, so the j-th free
         # slot of block b lands where b's running room first exceeds j
@@ -592,11 +646,12 @@ def _class_layout(prob: ShardedLCCProblem) -> ClassLayout:
         flat = r * t_cols + offsets[b] + col
         slot_u[k].reshape(-1)[flat] = u
         slot_v[k].reshape(-1)[flat] = vc
+        slot_acc[k].reshape(-1)[flat] = acc
 
     widths = tuple((int(ladder[x // nc]), int(ladder[x % nc])) for x in pairs)
     fetch = tuple((int(ladder[c]), int(cls_cap[c])) for c in fetched_cls)
     return ClassLayout(widths, tuple(int(c) for c in caps), slot_u, slot_v,
-                       fetch, fetch_idx)
+                       slot_acc, fetch, fetch_idx)
 
 
 def _build_sharded_problem(csr, p, n_rounds, cache, width, dedup_rounds,

@@ -373,12 +373,13 @@ def _setup_lcc(cfg, mesh: Mesh, meta):
         jax.ShapeDtypeStruct((p, n_loc), I32),
         jax.ShapeDtypeStruct((p, cfg.n_rounds, e_chunk), I32),
         jax.ShapeDtypeStruct((p, cfg.n_rounds, e_chunk), I32),
+        jax.ShapeDtypeStruct((p, cfg.n_rounds, e_chunk), I32),
         jax.ShapeDtypeStruct((p, cfg.n_rounds, p, s_max), I32),
         jax.ShapeDtypeStruct((c, w), I32),
     )
     shards = tuple(
         NamedSharding(flat, P("dev"))
-        for _ in range(5)
+        for _ in range(6)
     ) + (NamedSharding(flat, P()),)
     meta["note"] = "paper LCC engine; flat 1D mesh over all chips"
     return fn, sds, shards, meta

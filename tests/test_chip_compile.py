@@ -154,7 +154,7 @@ def _engine_args(prob, mesh):
     return [
         jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharded)
         for x in (prob.rows_ext, prob.degrees, layout.slot_u, layout.slot_v,
-                  layout.fetch_idx)
+                  layout.slot_acc, layout.fetch_idx)
     ] + [jax.ShapeDtypeStruct(prob.cache_rows.shape, prob.cache_rows.dtype,
                               sharding=NamedSharding(mesh, P()))]
 
@@ -199,11 +199,74 @@ def test_class_ordered_pairwise_needs_less_than_padded(topo, monkeypatch):
     assert temps(classed) < temps(padded)
 
 
+def _without_rows(csr, p, n_rounds, cache=None):
+    """The problem's schedule with one zero-stride sentinel row standing
+    in for every padded row: what a compile of the epoch program reads
+    (its shapes, and the arrays of ``class_layout``), with no row table
+    in host memory."""
+    from repro.core import rma
+    from repro.core.csr import to_padded_rows
+    from repro.core.partition import partition_1d
+
+    part = partition_1d(csr.n, p)
+    n_loc, w = int(max(part.sizes())), int(csr.max_degree)
+    cache_ids = cache.vertex_ids if cache else np.zeros(0, np.int64)
+    edge_u, edge_vc, edge_mask, serve_idx, e_max, nr, s_max = (
+        rma._compile_schedule(
+            [rma._edge_worklist(csr, part, k) for k in range(p)], part,
+            n=csr.n, n_loc=n_loc, cache_ids=cache_ids,
+            n_rounds_req=n_rounds, dedup_rounds=True))
+    degrees = np.zeros((p, n_loc), np.int32)
+    for k in range(p):
+        degrees[k, :part.hi(k) - part.lo(k)] = csr.degrees[
+            part.lo(k):part.hi(k)]
+    prob = rma.ShardedLCCProblem(
+        rows_ext=np.broadcast_to(np.int32(csr.n), (p, n_loc + 1, w)),
+        degrees=degrees, edge_u=edge_u, edge_vc=edge_vc, edge_mask=edge_mask,
+        serve_idx=serve_idx,
+        cache_rows=(to_padded_rows(csr, w, sentinel=csr.n, vertices=cache_ids)
+                    if cache else np.zeros((0, w), np.int32)),
+        n=csr.n, p=p, width=w, n_loc=n_loc, e_max=e_max, n_rounds=nr,
+        s_max=s_max, cache_ids=cache_ids)
+    prob.part = part
+    return prob
+
+
+def test_count_once_s15_program_fits_the_temporaries_of_both_directions(
+        topo):
+    """The benchmark's Graph500 S15 epoch program at 8 rounds, comparing
+    each undirected edge once, needs no more temporary memory than the
+    3.23 GiB of the program that compared both directions of every
+    edge, in about half the compares (1.647e11 an epoch) and 20 class
+    blocks."""
+    import json
+    import pathlib
+
+    from benchmarks.chip.graph import kronecker_edges
+    from repro.core.async_engine import make_lcc_fn
+    from repro.core.csr import from_edges
+    from repro.core.rma import class_layout
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cfg = json.loads(
+        (root / "benchmarks/chip/configs/g500-s15.json").read_text())
+    csr = from_edges(kronecker_edges(cfg, 1), 1 << cfg["scale"])
+    prob = _without_rows(csr, 1, cfg["engine"]["n_rounds"])
+    lay = class_layout(prob)
+    assert len(lay.caps) == 20
+    assert abs(lay.compares / 1.647e11 - 1) < 0.01
+    mesh = Mesh(np.array(topo.devices[:1]), ("dev",))
+    compiled = make_lcc_fn(prob, mesh, method="pairwise").lower(
+        *_engine_args(prob, mesh)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 3.23 * 2**30
+
+
 def test_class_width_exchange_compiles_on_2x2(topo):
     """The p=4 epoch program with cache rows compiles for the 2x2 mesh
     with one exchange a round (the first before the round loop, the
     next issued inside it ahead of the compare), and no operand of them
-    is as wide as the rows: the rows cross at their degree class."""
+    is as wide as the rows: the rows cross at their degree class; the
+    counts for other devices' rows cross once, summed."""
     import re
 
     from repro.core.async_engine import make_lcc_fn
@@ -216,11 +279,18 @@ def test_class_width_exchange_compiles_on_2x2(topo):
         csr, 4, n_rounds=2, cache=build_static_degree_cache(csr.degrees, 8))
     assert len(class_layout(prob).fetch) == 3
     mesh = Mesh(np.array(topo.devices), ("dev",))
-    text = make_lcc_fn(prob, mesh, method="pairwise").lower(
-        *_engine_args(prob, mesh)).compile().as_text()
+    compiled = make_lcc_fn(prob, mesh, method="pairwise").lower(
+        *_engine_args(prob, mesh)).compile()
+    mem = compiled.memory_analysis()
+    # each device's share fits one v5e chip's 16 GB of HBM
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+    text = compiled.as_text()
     a2a = [line for line in text.splitlines() if " all-to-all(" in line]
     assert len(a2a) == prob.n_rounds == 2
     dims = {int(d) for line in a2a
             for shape in re.findall(r"s32\[([0-9,]+)\]", line)
             for d in shape.split(",")}
     assert dims and prob.width not in dims
+    # the reduce-scatter, which the compiler may make an all-reduce
+    assert len([line for line in text.splitlines()
+                if " reduce-scatter(" in line or " all-reduce(" in line]) == 1

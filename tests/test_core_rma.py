@@ -387,16 +387,24 @@ GRAPHS = {
 }
 
 
+def _lower_first(deg, a, b):
+    """Whether edge slot (a, b) is the one the epoch program compares:
+    a is the lower endpoint by (degree, id)."""
+    return (int(deg[a]), int(a)) < (int(deg[b]), int(b))
+
+
 @pytest.mark.parametrize("graph", sorted(GRAPHS))
 @pytest.mark.parametrize("p,cache_rows,n_rounds", [
     (1, 0, 3), (4, 0, 2), (4, 16, 3),
 ])
 def test_class_layout_places_every_real_slot_once(graph, p, cache_rows,
                                                   n_rounds):
-    """Every real edge slot sits in exactly one block, as wide as both
-    its rows or wider (the narrowest class that holds each), a fetched
-    slot in the round that fetches its row; resolving the slots on the
-    host at their blocks' widths gives the exact triangle counts; the
+    """Every undirected edge sits in exactly one slot, on the owner of
+    its lower (degree, id) endpoint u, in a block of the narrowest
+    classes that hold both rows, a fetched slot in the round that
+    fetches its row, its accumulator index v's row on v's owner;
+    resolving the slots on the host at their blocks' widths and adding
+    each count to both endpoints gives the exact triangle counts; the
     evaluated compares are what ``schedule_counts`` reports."""
     from repro.core.triangles import triangles_per_vertex
 
@@ -408,55 +416,91 @@ def test_class_layout_places_every_real_slot_once(graph, p, cache_rows,
     ladder = class_widths(prob.width)
     assert max(max(w) for w in lay.widths) <= prob.width
     assert all(wu in ladder and wv in ladder for wu, wv in lay.widths)
+    assert all(wu <= wv for wu, wv in lay.widths)
     assert len(set(lay.widths)) == len(lay.widths)
     counts = schedule_counts(prob, csr.degrees)
     assert counts["class_blocks"] == len(lay.caps)
     assert counts["padded_compares"] == lay.compares
+    assert 2 * counts["evaluated_edge_slots"] == counts["real_edge_slots"]
     assert lay.compares <= prob.e_max * prob.width ** 2
     assert lay.slot_u.shape == (p, prob.n_rounds, sum(lay.caps))
+    assert lay.slot_acc.shape == lay.slot_u.shape
 
+    deg = csr.degrees
     sent, e_chunk = prob.sentinel, prob.e_max // prob.n_rounds
-    base_fetch = prob.n_loc + 1 + prob.cache_rows.shape[0]
+    nl1 = prob.n_loc + 1
+    base_fetch = nl1 + prob.cache_rows.shape[0]
     part = partition_1d(csr.n, p)
-    want_t = triangles_per_vertex(csr)
+    src = np.repeat(np.arange(csr.n), deg)
+    undirected = sorted(zip(src[src < csr.adjacencies].tolist(),
+                            csr.adjacencies[src < csr.adjacencies].tolist()))
+    s = np.zeros(csr.n, np.int64)
+    got_edges = []
     for k in range(p):
         real = np.flatnonzero(prob.edge_mask[k])
-        want = [(u, int(_combined_ids(prob, k, r)[vc]),
-                 r if vc >= base_fetch else -1)
-                for u, vc, r in zip(prob.edge_u[k, real].tolist(),
-                                    prob.edge_vc[k, real].tolist(),
-                                    (real // e_chunk).tolist())]
+        want = []
+        for u, vc, r in zip(prob.edge_u[k, real].tolist(),
+                            prob.edge_vc[k, real].tolist(),
+                            (real // e_chunk).tolist()):
+            v = int(_combined_ids(prob, k, r)[vc])
+            if _lower_first(deg, part.lo(k) + u, v):
+                want.append((u, v, r if vc >= base_fetch else -1))
         got = []
-        s = np.zeros(prob.n_loc + 1, np.int64)
         for r in range(prob.n_rounds):
             for wu, wv, lo, hi in lay.blocks:
                 ids = _class_ids(prob, lay, k, r, wv)
-                for u, vc in zip(lay.slot_u[k, r, lo:hi],
-                                 lay.slot_v[k, r, lo:hi]):
+                for u, vc, a in zip(lay.slot_u[k, r, lo:hi],
+                                    lay.slot_v[k, r, lo:hi],
+                                    lay.slot_acc[k, r, lo:hi]):
                     if u == prob.n_loc:  # an empty slot
-                        assert vc == prob.n_loc
+                        assert vc == prob.n_loc and a == k * nl1 + prob.n_loc
                         continue
-                    got.append((int(u), int(ids[vc]),
-                                r if vc >= base_fetch else -1))
+                    v = int(ids[vc])
+                    got.append((int(u), v, r if vc >= base_fetch else -1))
+                    q = int(part.owner(v))
+                    assert a == q * nl1 + v - part.lo(q)
                     row_u = prob.rows_ext[k][u]
-                    row_v = _row_of(prob, int(ids[vc]))
+                    row_v = _row_of(prob, v)
                     du, dv = (row_u < sent).sum(), (row_v < sent).sum()
                     assert du <= wu and dv <= wv
                     # the narrowest class that holds the row
                     assert wu == ladder[np.searchsorted(ladder, du)]
                     assert wv == ladder[np.searchsorted(ladder, dv)]
                     a, b = row_u[:wu], row_v[:wv]
-                    s[u] += np.intersect1d(a[a < sent], b[b < sent]).size
-        # each real slot once, reading v's row; a fetched one in its own
+                    c = np.intersect1d(a[a < sent], b[b < sent]).size
+                    s[part.lo(k) + u] += c
+                    s[v] += c
+        # each kept slot once, reading v's row; a fetched one in its own
         # round, any other in any round
         assert sorted(got) == sorted(want)
-        lo, hi = part.lo(k), part.hi(k)
-        assert np.array_equal(s[: hi - lo] // 2, want_t[lo:hi])
+        got_edges += [tuple(sorted((part.lo(k) + u, v))) for u, v, _ in got]
+    assert sorted(got_edges) == undirected
+    assert np.array_equal(s // 2, triangles_per_vertex(csr))
+
+
+def test_class_layout_compares_each_edge_once_in_about_half_the_compares():
+    """On a power-law graph at p=1 the layout of one slot per undirected
+    edge evaluates at most 0.55x the compares of one with both
+    directions of every edge in the same degree-class blocks."""
+    csr = powerlaw_graph(2000, 40, seed=4)
+    nr = 4
+    prob = build_sharded_problem(csr, 1, n_rounds=nr)
+    lay = class_layout(prob)
+    ladder = class_widths(prob.width)
+    cls = ladder[np.searchsorted(ladder, csr.degrees)]
+    u = np.repeat(np.arange(csr.n), csr.degrees)
+    pairs, n_slots = np.unique(
+        np.stack([cls[u], cls[csr.adjacencies]], 1), axis=0,
+        return_counts=True)
+    both = nr * sum(-(-c // nr) * wu * wv for (wu, wv), c in
+                    zip(pairs.tolist(), n_slots.tolist()))
+    assert len(lay.caps) < len(pairs)
+    assert lay.compares <= 0.55 * both
 
 
 def test_class_layout_of_a_regular_graph_is_one_padded_block():
     """Degrees all in one class: one block as wide as the rows, the
-    padded all-pairs compare of every slot."""
+    padded all-pairs compare of every undirected edge once."""
     n, k = 400, 90  # circulant graph, every degree 180
     ring = np.arange(n)
     edges = np.concatenate([np.stack([ring, (ring + d) % n], 1)
@@ -465,7 +509,7 @@ def test_class_layout_of_a_regular_graph_is_one_padded_block():
     lay = class_layout(prob)
     assert prob.width == 2 * k
     assert lay.widths == ((2 * k, 2 * k),)
-    assert lay.compares == prob.e_max * prob.width ** 2
+    assert lay.compares == prob.e_max // 2 * prob.width ** 2
 
 
 def test_class_layout_follows_changes_made_in_place():
@@ -475,11 +519,15 @@ def test_class_layout_follows_changes_made_in_place():
     prob = build_sharded_problem(csr, 1, n_rounds=2)
     full = class_layout(prob)
     assert class_layout(prob) is full
+    assert 2 * (full.slot_u != prob.n_loc).sum() == prob.edge_mask.sum()
     prob.edge_mask[:, prob.e_max // 2:] = False
     half = class_layout(prob)
     assert half is not full
     real = (half.slot_u != prob.n_loc).sum()
-    assert real == prob.edge_mask.sum() < (full.slot_u != prob.n_loc).sum()
+    e = np.flatnonzero(prob.edge_mask[0])
+    kept = sum(_lower_first(csr.degrees, u, v) for u, v in
+               zip(prob.edge_u[0, e].tolist(), prob.edge_vc[0, e].tolist()))
+    assert real == kept < (full.slot_u != prob.n_loc).sum()
 
 
 @pytest.mark.parametrize("graph", sorted(GRAPHS))
@@ -488,9 +536,11 @@ def test_class_layout_follows_changes_made_in_place():
 ])
 def test_class_layout_serves_each_fetched_row_once_at_its_class(
         graph, p, cache_rows, n_rounds):
-    """The per-class serve lists hold every row of the serve lists once,
-    in the list of the class of its degree, within that class's cap; a
-    class is fetched only where some row of it is served."""
+    """The per-class serve lists hold exactly the rows that kept slots
+    read from another device and no cache row serves: each once, in its
+    round, in the list of the class of its degree, within that class's
+    cap, and in the schedule's serve list; a class is fetched only where
+    some row of it is served."""
     csr = GRAPHS[graph]()
     cache = (build_static_degree_cache(csr.degrees, cache_rows)
              if cache_rows else None)
@@ -501,12 +551,27 @@ def test_class_layout_serves_each_fetched_row_once_at_its_class(
     assert all(c > 0 for c in caps)
     assert lay.fetch_idx.shape == (p, prob.n_rounds, p, sum(caps))
     assert lay.exchange_ids == sum(w * c for w, c in lay.fetch)
+    part = partition_1d(csr.n, p)
+    cached = set(prob.cache_ids.tolist())
+    e_chunk = prob.e_max // prob.n_rounds
+    # read[q][r][k]: the rows of q that device k's kept slots read in
+    # round r, neither local nor cached
+    read = [[[set() for _ in range(p)] for _ in range(prob.n_rounds)]
+            for _ in range(p)]
+    for k in range(p):
+        for e in np.flatnonzero(prob.edge_mask[k]):
+            r = e // e_chunk
+            u = part.lo(k) + int(prob.edge_u[k, e])
+            v = int(_combined_ids(prob, k, r)[prob.edge_vc[k, e]])
+            q = int(part.owner(v))
+            if (_lower_first(csr.degrees, u, v) and q != k
+                    and v not in cached):
+                read[q][r][k].add(v - part.lo(q))
     seen = set()
     for q in range(p):
         for r in range(prob.n_rounds):
             for k in range(p):
-                want = prob.serve_idx[q, r, k]
-                want = sorted(want[want < prob.n_loc].tolist())
+                served = prob.serve_idx[q, r, k]
                 got, lo = [], 0
                 for wc, sc in lay.fetch:
                     rows = lay.fetch_idx[q, r, k, lo:lo + sc]
@@ -517,18 +582,21 @@ def test_class_layout_serves_each_fetched_row_once_at_its_class(
                         seen.add(wc)
                     got += rows.tolist()
                     lo += sc
-                assert sorted(got) == want
+                assert sorted(got) == sorted(read[q][r][k])
+                assert set(got) <= set(served[served < prob.n_loc].tolist())
     assert seen == {wc for wc, _ in lay.fetch}
     if p == 1:
         assert lay.fetch == ()
 
 
 def test_schedule_counts_exchange_matches_the_edge_list():
-    """The exchange counts of ``schedule_counts`` equal those taken
-    straight from the edge list: the busiest device's remote and cached
-    reads, the rows it receives and the real ids it ships per round, and
-    the wire ids every device ships each peer (each class's longest
-    list at the class's width)."""
+    """The counts of ``schedule_counts`` equal those taken straight from
+    the edge list, where each undirected edge is kept once, on the
+    device of its lower (degree, id) endpoint: the slots kept, and on
+    the busiest device (the most kept slots) their real compares, its
+    remote and cached reads, the rows it receives and the real ids it
+    ships per round, and the wire ids every device ships each peer
+    (each class's longest list at the class's width)."""
     csr = powerlaw_graph(96, 6, seed=4)
     p, nr = 4, 3
     cache = build_static_degree_cache(csr.degrees, 8)
@@ -545,15 +613,18 @@ def test_schedule_counts_exchange_matches_the_edge_list():
               for v in csr.adjacencies[csr.offsets[u]:csr.offsets[u + 1]]]
              for k in range(p)]
     chunk = -(-max(len(e) for e in edges) // nr)
+    # a slot's round is its place in the worklist of both directions
+    kept = [[(i // chunk, u, v) for i, (u, v) in enumerate(edges[j])
+             if _lower_first(deg, u, v)] for j in range(p)]
     # want[j][r][q]: the rows device j fetches from q in round r
     want = [[[set() for _ in range(p)] for _ in range(nr)] for _ in range(p)]
     for j in range(p):
-        for i, (u, v) in enumerate(edges[j]):
+        for r, u, v in kept[j]:
             q = int(part.owner(v))
             if q != j and v not in cached:
-                want[j][i // chunk][q].add(v)
-    k = max(range(p), key=lambda j: len(edges[j]))
-    remote = [v for u, v in edges[k] if part.owner(v) != k]
+                want[j][r][q].add(v)
+    k = max(range(p), key=lambda j: len(kept[j]))
+    remote = [v for _, u, v in kept[k] if part.owner(v) != k]
     cap = {}
     for j in range(p):
         for r in range(nr):
@@ -562,6 +633,10 @@ def test_schedule_counts_exchange_matches_the_edge_list():
                     n_c = sum(ladder[np.searchsorted(ladder, deg[v])] == wc
                               for v in want[j][r][q])
                     cap[wc] = max(cap.get(wc, 0), n_c)
+    assert got["real_edge_slots"] == csr.adjacencies.size
+    assert got["evaluated_edge_slots"] == sum(map(len, kept)) == csr.m // 2
+    assert got["real_pair_compares"] == sum(deg[u] * deg[v]
+                                            for _, u, v in kept[k])
     assert got["remote_reads"] == len(remote)
     assert got["cache_reads"] == sum(v in cached for v in remote)
     assert got["fetched_rows"] == sum(len(s) for r in want[k] for s in r)
